@@ -23,8 +23,8 @@ config must be cost-gated back to the jnp lowering — so a routing
 regression fails CI instead of landing silently.
 
 On this CPU container the kernels resolve to their ref (pure-jnp) paths;
-the TPU target flips ``kops.DEFAULT_IMPL`` to "pallas" and the same plan
-drives the real kernels.
+on a TPU the impl resolves to "pallas" and the same plan drives the real
+kernels.
 """
 from __future__ import annotations
 

@@ -21,8 +21,8 @@ silently.
 
 On this CPU container the kernels resolve to their ref (pure-jnp) paths,
 so timings measure planner + dispatch overhead and XLA's view of the
-restructured program; the TPU target flips ``kops.DEFAULT_IMPL`` to
-"pallas" and the same plan drives the real kernels.
+restructured program; on a TPU the impl resolves to "pallas" and the
+same plan drives the real kernels.
 """
 from __future__ import annotations
 

@@ -214,6 +214,7 @@ def tune(spec: "reg.KernelSpec", meta: dict, impl: str,
     # time the grid on a synthetic same-bucket workload
     bench_meta = dict(meta, n=size_bucket(n))
     best_params, best_t = defaults, float("inf")
+    failures = []
     with obs.span("autotune.tune", kernel=spec.name, n=size_bucket(n),
                   impl=impl) as tsp:
         for cand in _grid(spec.tune_space):
@@ -223,9 +224,10 @@ def tune(spec: "reg.KernelSpec", meta: dict, impl: str,
                 faults.maybe_raise("autotune.time")
                 go = spec.make_bench(bench_meta, cand, impl)
                 t = _time_candidate(go)
-            except Exception:
+            except Exception as e:
                 obs.event("autotune.candidate", kernel=spec.name,
                           skipped=True, **cand)
+                failures.append(f"{cand}: {type(e).__name__}: {e}")
                 continue  # candidate invalid for this shape — skip
             obs.event("autotune.candidate", kernel=spec.name,
                       us=round(t * 1e6, 2), **cand)
@@ -234,6 +236,16 @@ def tune(spec: "reg.KernelSpec", meta: dict, impl: str,
         tsp.set("best", dict(best_params))
         if best_t < float("inf"):
             tsp.set("us", round(best_t * 1e6, 2))
+    if best_t == float("inf") and impl == "pallas":
+        # no candidate compiled on the device: caching the defaults would
+        # only hide the failure until the plan launches them
+        from ..errors import KernelCompileError
+
+        raise KernelCompileError(
+            f"autotune: every {spec.name} candidate failed on pallas; "
+            + " | ".join(failures[:3]),
+            kernel=spec.name, impl=impl, dtype=str(np.dtype(
+                meta.get("dtype", "f8"))), n=n)
     c = _load()
     c[_key(spec.name, meta.get("dtype", "f8"), n, impl, k=k, dims=dims)] = {
         "params": best_params,

@@ -43,7 +43,7 @@ from dataclasses import dataclass, replace
 from math import ceil, log2
 from typing import Optional
 
-from ...roofline.analysis import HW_V5E
+from ...roofline.analysis import device_peaks
 
 #: route when kernel_s <= jnp_s * (1 + ROUTE_MARGIN): prefer the kernel
 #: on a near-tie (it strictly reduces HBM traffic on the real target).
@@ -111,8 +111,14 @@ REJECT_UNKNOWN = CostEstimate(
 
 
 def _roofline_s(bytes_moved: float, flops: float) -> float:
-    return max(bytes_moved / HW_V5E["hbm_bw"],
-               flops / HW_V5E["peak_flops_bf16"])
+    _, hw, _ = device_peaks()
+    return max(bytes_moved / hw["hbm_bw"], flops / hw["peak_flops_bf16"])
+
+
+def _peaks_tag() -> str:
+    """Which chip's peaks priced the estimate (a target off the TPU)."""
+    kind, _, is_target = device_peaks()
+    return f"peaks={kind}" + (" (target)" if is_target else "")
 
 
 def _pad(n: int, block: int) -> int:
@@ -230,8 +236,8 @@ def cost_hash_build(meta: dict) -> CostEstimate:
 
 
 def cost_hash_probe(meta: dict) -> CostEstimate:
-    """One-hot MXU membership probe vs. the generic vectorized binary
-    search: the kernel streams the query block against a VMEM key tile
+    """Key-sweep membership probe vs. the generic vectorized binary
+    search: the kernel compares each query tile against every table key
     (n*K compares, ONCE for every output column of a fused probe), the
     jnp lowering pays log2(K) dependent random loads per row plus a
     per-column streaming pass."""
@@ -279,8 +285,8 @@ def cost_group_build(meta: dict) -> CostEstimate:
 
 
 def cost_group_probe(meta: dict) -> CostEstimate:
-    """m:n fan-out probe: the fused one-hot membership + match-count
-    tile vs. the generic vectorized binary search.  BOTH routes then
+    """m:n fan-out probe: the fused membership + match-count key sweep
+    vs. the generic vectorized binary search.  BOTH routes then
     pay the shared two-phase expansion (exclusive scan + repeat/gather
     into the static expansion buffer), priced by the expansion factor
     ``out``/``n`` the planner lifts off the vecbuilder size hints."""
@@ -383,4 +389,7 @@ def estimate(spec, meta: dict) -> CostEstimate:
     hook = getattr(spec, "cost", None)
     if hook is None:
         return CostEstimate(0.0, 0.0, True, "no cost hook: always route")
-    return _calibrated(spec, meta, hook(meta))
+    est = hook(meta)
+    if est is not REJECT_UNKNOWN:
+        est = replace(est, why=f"{est.why} {_peaks_tag()}")
+    return _calibrated(spec, meta, est)

@@ -146,6 +146,64 @@ def _scalar_kind_ok(ty: wt.WeldType, spec: reg.KernelSpec) -> bool:
     return isinstance(ty, wt.Scalar) and ty.kind in spec.elem_kinds
 
 
+def _kinds(ty: wt.WeldType) -> Tuple[str, ...]:
+    """Scalar kinds of a (vector / struct of) scalar type."""
+    if isinstance(ty, wt.Struct):
+        return tuple(k for f in ty.fields for k in _kinds(f))
+    if isinstance(ty, wt.Vec):
+        return _kinds(ty.elem)
+    if isinstance(ty, wt.Scalar):
+        return (ty.kind,)
+    return ()
+
+
+#: hash-route kernels: what enters them is the key (packed when it spans
+#: several columns); their value columns are gathered or sorted outside.
+_KEYED = {"dict_hash_build": "ret", "group_build": "ret",
+          "hash_probe": "dict", "group_probe": "dict"}
+
+
+def _tpu_reject(kc: ir.KernelCall) -> Optional[str]:
+    """Why a match cannot take its Pallas kernel on the TPU, or None.
+
+    Mosaic lowers no 64-bit element, so every scalar that enters the
+    kernel must be among the spec's ``tpu_kinds``; a multi-column key
+    packs into the 64-bit key space and is rejected as such.  The match
+    then keeps the generic lowering: it is never launched, failed and
+    quarantined."""
+    spec = reg.get(kc.kernel)
+    side = _KEYED.get(kc.kernel)
+    if side is not None:
+        dty = kc.ret_ty if side == "ret" else kc.args[0].ty
+        if isinstance(dty.key, wt.Struct) and len(dty.key.fields) > 1:
+            return (f"packed 64-bit key ({len(dty.key.fields)} columns): "
+                    "the TPU hash kernels key an int32 table")
+        kinds = _kinds(dty.key)
+        seg = reg.available("dict_group_sum")
+        if (kc.kernel == "dict_hash_build" and seg is not None
+                and dict(kc.params)["capacity"] <= seg.max_segments):
+            # the values are accumulated by the one-hot segment kernel,
+            # which only takes capacities within its tile (kops serves
+            # larger ones with the jnp scatter)
+            bad = sorted(set(_kinds(dty.val)) - set(seg.tpu_kinds))
+            if bad:
+                return (f"dtype {','.join(bad)}: the TPU segment kernel "
+                        f"accumulates {','.join(seg.tpu_kinds)} only")
+    elif kc.kernel == "dict_group_sum":
+        kinds = _kinds(kc.ret_ty.val)
+    elif kc.kernel in ("map_elementwise", "matmul", "matvec"):
+        # the staged body / operands run inside the kernel itself
+        kinds = _kinds(kc.ret_ty) + tuple(
+            k for a in kc.args for k in _kinds(ir.typeof(a)))
+    else:
+        kinds = _kinds(kc.ret_ty)
+    bad = sorted(set(kinds) - set(spec.tpu_kinds))
+    if bad:
+        return (f"dtype {','.join(bad)}: the TPU kernel compiles for "
+                f"{','.join(spec.tpu_kinds)} only")
+    return None
+
+
 def _static_cap(e: Optional[ir.Expr], dense: Shapes) -> Optional[int]:
     """Resolve a capacity / size-hint expression to a concrete int.
     Accepts anything the backend's static evaluator can resolve —
@@ -1096,7 +1154,8 @@ def plan_kernels(
     stats.setdefault("kernelize.matched", 0)
     kplan = stats.setdefault(
         "kernelplan",
-        {"mode": mode, "routed": {}, "rejected": {}, "costs": []},
+        {"mode": mode, "impl": impl, "routed": {}, "rejected": {},
+         "costs": []},
     )
     dense: Shapes = {
         k: tuple(v) if v is not None else None
@@ -1148,8 +1207,20 @@ def plan_kernels(
             _obs.event("kernelplan.candidate", kernel=kc.kernel,
                        n=meta.get("n"), routed=False, why="quarantined")
             return orig
+        tpu_why = _tpu_reject(kc) if impl == "pallas" else None
+        if tpu_why is not None:
+            kplan["rejected"][kc.kernel] = (
+                kplan["rejected"].get(kc.kernel, 0) + 1
+            )
+            kplan["costs"].append({
+                "kernel": kc.kernel, "routed": False, "why": tpu_why,
+                "kernel_us": 0.0, "jnp_us": 0.0,
+            })
+            _obs.event("kernelplan.candidate", kernel=kc.kernel,
+                       n=meta.get("n"), routed=False, why=tpu_why)
+            return orig
         if kc.kernel in ("hash_probe", "group_probe"):
-            # the one-hot tile is block x capacity: an unknown or
+            # the probe sweeps the whole table: an unknown or
             # oversized dict cannot take the kernel even under "always"
             spec = reg.available(kc.kernel)
             k = meta.get("k")

@@ -91,6 +91,10 @@ class KernelSpec:
     #: module-default value per tunable (what runs untuned; also what the
     #: autotuner bakes into the plan when timing is unavailable).
     tune_defaults: Dict[str, int] = field(default_factory=dict)
+    #: scalar kinds the Pallas kernel compiles for on the TPU (Mosaic
+    #: lowers no 64-bit element): the planner rejects an ``impl="pallas"``
+    #: match whose kernel operands fall outside them, and never launches it.
+    tpu_kinds: Tuple[str, ...] = ("f32", "i32")
 
 
 _REGISTRY: Dict[str, KernelSpec] = {}
@@ -218,6 +222,19 @@ def _as_col(v, n):
     return jnp.broadcast_to(v, (n,) + v.shape)
 
 
+def _hash_keys(keys):
+    """The hash kernels' key space for a (possibly struct) key: a single
+    int column of at most 32 bits keys an int32 table — the only width
+    Mosaic lowers — and every other key packs into the i64 space the
+    generic lowering compares in (jaxgen ``_pack_keys``).  Build and
+    probe sides derive it from the same key dtype, so they always agree."""
+    if (not isinstance(keys, tuple)
+            and jnp.issubdtype(keys.dtype, jnp.integer)
+            and jnp.dtype(keys.dtype).itemsize <= 4):
+        return keys.astype(jnp.int32)
+    return _pack_keys(keys)
+
+
 # ---------------------------------------------------------------------------
 # Adapters
 # ---------------------------------------------------------------------------
@@ -342,24 +359,22 @@ def _exec_dict_hash_build(args, params, fns, impl):
     nk = int(params.get("n_keys", 1))
     nv = int(params.get("n_vals", 1))
     block = params.get("block")
-    key_cols = [
-        _as_col(fns[j](idx, elem), n).astype(jnp.int64) for j in range(nk)
-    ]
+    key_cols = [_as_col(fns[j](idx, elem), n) for j in range(nk)]
     vals = [_as_col(fns[nk + j](idx, elem), n) for j in range(nv)]
     if params.get("has_pred"):
         mask = _as_col(fns[nk + nv](idx, elem), n).astype(bool)
     else:
         mask = jnp.ones((n,), dtype=bool)
-    packed = _pack_keys(tuple(key_cols) if nk > 1 else key_cols[0])
-    sentinel_clash = jnp.any(mask & (packed == _ht.EMPTY))
-    pk = jnp.where(mask, packed, _ht.EMPTY)
+    packed = _hash_keys(tuple(key_cols) if nk > 1 else key_cols[0])
+    empty = _ht.empty_of(packed.dtype)
+    sentinel_clash = jnp.any(mask & (packed == empty))
+    pk = jnp.where(mask, packed, empty)
     ctab = _ht.table_size(cap)
     slots, table, used = kops.hash_to_slot(pk, ctab, impl=impl, block=block)
     overflow = (used > cap) | sentinel_clash
     # table slot -> compact position in ascending packed order (matches
     # the generic keyed finalize, so lookups/decodes are layout-identical)
-    big = jnp.iinfo(jnp.int64).max
-    tsort = jnp.where(table == _ht.EMPTY, big, table)
+    tsort = jnp.where(table == empty, jnp.iinfo(table.dtype).max, table)
     order = jnp.argsort(tsort)
     rank = jnp.zeros((ctab,), jnp.int32).at[order].set(
         jnp.arange(ctab, dtype=jnp.int32))
@@ -394,7 +409,7 @@ def _recover_key_cols(key_cols, mask, slots, cap, key_nps, overflow):
     overflow poisons the columns to -1."""
     outs = []
     for kc, knp in zip(key_cols, key_nps):
-        src = jnp.where(mask, kc, jnp.iinfo(jnp.int64).min)
+        src = jnp.where(mask, kc, jnp.iinfo(kc.dtype).min)
         ko = jax.ops.segment_max(src, slots.astype(jnp.int32),
                                  num_segments=cap + 1)[:cap]
         ko = ko.astype(np.dtype(knp))
@@ -419,11 +434,9 @@ def _probe_membership(args, params, fns, impl, nk, n_iters=None):
     n = arrays[0].shape[0]
     idx = jnp.arange(n, dtype=jnp.int64)
     elem = _elem_of(arrays)
-    key_cols = [
-        _as_col(fns[j](idx, elem), n).astype(jnp.int64) for j in range(nk)
-    ]
-    keys_q = _pack_keys(tuple(key_cols) if nk > 1 else key_cols[0])
-    packed_t = _pack_keys(d.keys)
+    key_cols = [_as_col(fns[j](idx, elem), n) for j in range(nk)]
+    keys_q = _hash_keys(tuple(key_cols) if nk > 1 else key_cols[0])
+    packed_t = _hash_keys(d.keys)
     cap = packed_t.shape[0]
     cnt = jnp.maximum(jnp.asarray(d.count, jnp.int64), 0)
     sizes = jnp.zeros((n,), jnp.int64) if is_group else None
@@ -431,7 +444,7 @@ def _probe_membership(args, params, fns, impl, nk, n_iters=None):
         pos = jnp.zeros((n,), jnp.int32)
         found = jnp.zeros((n,), dtype=bool)
     else:
-        big = jnp.iinfo(jnp.int64).max
+        big = jnp.iinfo(packed_t.dtype).max
         neut = jnp.where(jnp.arange(cap) < cnt, packed_t, big)
         if is_group:
             pos, found, sizes = kops.group_probe(
@@ -539,17 +552,16 @@ def _exec_group_build(args, params, fns, impl):
     cap = int(params["capacity"])
     nk = int(params.get("n_keys", 1))
     block = params.get("block")
-    key_cols = [
-        _as_col(fns[j](idx, elem), n).astype(jnp.int64) for j in range(nk)
-    ]
+    key_cols = [_as_col(fns[j](idx, elem), n) for j in range(nk)]
     val = _as_col(fns[nk](idx, elem), n)
     if params.get("has_pred"):
         mask = _as_col(fns[nk + 1](idx, elem), n).astype(bool)
     else:
         mask = jnp.ones((n,), dtype=bool)
-    packed = _pack_keys(tuple(key_cols) if nk > 1 else key_cols[0])
-    sentinel_clash = jnp.any(mask & (packed == _ht.EMPTY))
-    pk = jnp.where(mask, packed, _ht.EMPTY)
+    packed = _hash_keys(tuple(key_cols) if nk > 1 else key_cols[0])
+    empty = _ht.empty_of(packed.dtype)
+    sentinel_clash = jnp.any(mask & (packed == empty))
+    pk = jnp.where(mask, packed, empty)
     cslots, offsets, used = kops.group_build(pk, cap, impl=impl, block=block)
     overflow = (used > cap) | sentinel_clash
     # CSR payload ordering: ascending compact slot, stable — within a
@@ -625,7 +637,7 @@ def _exec_map_elementwise(args, params, fns, impl):
     def body(*cols):
         # the staged lambda is (i, x); map-chain matching guarantees the
         # index is unused, so bind a dummy scalar.
-        return fns[0](jnp.int64(0), _elem_of(list(cols)))
+        return fns[0](0, _elem_of(list(cols)))
 
     return WVec(kops.map_elementwise(body, arrays, impl=impl,
                                      block=params.get("block")))
@@ -661,7 +673,7 @@ def _fp_vecmerger(arg_shapes, itemsize, params):
 def _fp_dict_group(arg_shapes, itemsize, params):
     n = arg_shapes[0][0] if arg_shapes and arg_shapes[0] else 0
     cap = int(params.get("capacity", 0))
-    pad = _pad_of(n, params.get("block") or 256)
+    pad = _pad_of(n, params.get("block") or _sr.BLOCK_N)
     # staged keys/mask + the stacked (n, 2) value matrix + K-compaction
     return (n + pad) * (4 + 2 * itemsize + 1) + cap * (3 * itemsize + 8)
 
@@ -786,7 +798,7 @@ def _bench_hash_build(meta, params, impl):
     # real workload sizes)
     n = min(int(meta["n"]), 8192)
     k = max(int(meta.get("k") or 256), 1)
-    keys = (jnp.arange(n, dtype=jnp.int64) % k) * 7 + 3
+    keys = (jnp.arange(n, dtype=jnp.int32) % k) * 7 + 3
     ctab = _ht.table_size(k)
 
     def go():
@@ -799,8 +811,8 @@ def _bench_hash_build(meta, params, impl):
 def _bench_hash_probe(meta, params, impl):
     n = int(meta["n"])
     k = max(int(meta.get("k") or 256), 1)
-    table = jnp.arange(k, dtype=jnp.int64) * 3
-    queries = (jnp.arange(n, dtype=jnp.int64) % (2 * k)) * 3  # ~50% hits
+    table = jnp.arange(k, dtype=jnp.int32) * 3
+    queries = (jnp.arange(n, dtype=jnp.int32) % (2 * k)) * 3  # ~50% hits
 
     def go():
         jax.block_until_ready(kops.dict_probe(
@@ -814,7 +826,7 @@ def _bench_group_build(meta, params, impl):
     # first-touch tuning stays cheap (same rationale as hash_build)
     n = min(int(meta["n"]), 8192)
     k = max(int(meta.get("k") or 256), 1)
-    keys = (jnp.arange(n, dtype=jnp.int64) % k) * 7 + 3
+    keys = (jnp.arange(n, dtype=jnp.int32) % k) * 7 + 3
 
     def go():
         jax.block_until_ready(kops.group_build(
@@ -826,9 +838,9 @@ def _bench_group_build(meta, params, impl):
 def _bench_group_probe(meta, params, impl):
     n = int(meta["n"])
     k = max(int(meta.get("k") or 256), 1)
-    table = jnp.arange(k, dtype=jnp.int64) * 3
+    table = jnp.arange(k, dtype=jnp.int32) * 3
     offsets = (jnp.arange(k + 1, dtype=jnp.int32) * 4)  # fan-out 4
-    queries = (jnp.arange(n, dtype=jnp.int64) % (2 * k)) * 3  # ~50% hits
+    queries = (jnp.arange(n, dtype=jnp.int32) % (2 * k)) * 3  # ~50% hits
 
     def go():
         jax.block_until_ready(kops.group_probe(
@@ -894,6 +906,7 @@ register(KernelSpec(
                 "segment sums (PageRank's edge scan)",
     max_segments=_sr.MAX_K,  # beyond this, kops serves the ref scatter:
                              # the cost gate prices that route as a loss
+    tpu_kinds=("f32",),
     execute=_exec_vecmerger_segment_sum,
     cost=_cost.cost_vecmerger,
     tune_space={"block": _sr.BLOCK_CANDIDATES},
@@ -911,10 +924,11 @@ register(KernelSpec(
     description="group-by-sum with dense int keys in [0, capacity) via "
                 "segment_sum + presence compaction",
     max_segments=_sr.MAX_K,
+    tpu_kinds=("f32",),
     execute=_exec_dict_group_sum,
     cost=_cost.cost_dict_group,
-    tune_space={"block": (128, 256, 512)},
-    tune_defaults={"block": 256},
+    tune_space={"block": _sr.BLOCK_CANDIDATES},
+    tune_defaults={"block": _sr.BLOCK_N},
     make_bench=_bench_dict_group,
     footprint=_fp_dict_group,
 ))
@@ -930,6 +944,7 @@ register(KernelSpec(
                 "side; also the group-by fallback beyond the dense "
                 "segment route's capacity)",
     max_segments=_ht.MAX_CAP,
+    tpu_kinds=("i32", "f32"),
     execute=_exec_dict_hash_build,
     cost=_cost.cost_hash_build,
     tune_space={"block": _ht.BLOCK_CANDIDATES},
@@ -944,10 +959,11 @@ register(KernelSpec(
     pattern="hash_probe",
     builder="vecbuilder",
     elem_kinds=("f32", "f64", "i32", "i64"),
-    description="one-hot MXU dict probe: one membership launch shared "
+    description="key-sweep dict probe: one membership launch shared "
                 "by every join output column (inner filter / left "
                 "fill-on-miss / anti), gathers outside the kernel",
     max_segments=_ht.MAX_CAP,
+    tpu_kinds=("i32",),
     execute=_exec_hash_probe,
     cost=_cost.cost_hash_probe,
     tune_space={"block": _hp.BLOCK_CANDIDATES},
@@ -966,6 +982,7 @@ register(KernelSpec(
                 "payloads) via hash-to-slot + slot-histogram compaction "
                 "— the m:n hash-join build side",
     max_segments=_ht.MAX_CAP,
+    tpu_kinds=("i32",),
     execute=_exec_group_build,
     cost=_cost.cost_group_build,
     tune_space={"block": _gb.BLOCK_CANDIDATES},
@@ -985,6 +1002,7 @@ register(KernelSpec(
                 "then the two-phase expansion (scan + repeat/gather) "
                 "outside the kernel",
     max_segments=_ht.MAX_CAP,
+    tpu_kinds=("i32",),
     execute=_exec_group_probe,
     cost=_cost.cost_group_probe,
     tune_space={"block": _hp.BLOCK_CANDIDATES},
@@ -1000,6 +1018,7 @@ register(KernelSpec(
     builder="-",
     elem_kinds=("f32", "f64"),
     description="tiled VMEM-blocked matmul for raised 2-D dot loops",
+    tpu_kinds=("f32",),
     execute=_exec_matmul,
     cost=_cost.cost_matmul,
     tune_space={"bm": _tm.BM_CANDIDATES, "bn": _tm.BN_CANDIDATES,
@@ -1016,6 +1035,7 @@ register(KernelSpec(
     builder="-",
     elem_kinds=("f32", "f64"),
     description="matrix-vector product through the tiled matmul kernel",
+    tpu_kinds=("f32",),
     execute=_exec_matvec,
     cost=_cost.cost_matmul,
     tune_space={"bm": _tm.BM_CANDIDATES, "bk": _tm.BK_CANDIDATES},

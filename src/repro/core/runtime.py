@@ -28,6 +28,7 @@ calls, under the recovery ladder) drives the same stages end-to-end.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -37,8 +38,18 @@ from typing import Dict, List, Optional, Tuple
 import jax
 
 # The Weld IR's i64/f64 scalars require x64; the LM stack specifies its
-# dtypes explicitly everywhere so this global is benign for it.
+# dtypes explicitly everywhere so this global is benign for it.  (Pallas
+# kernels trace with x64 off — see kernels/ops.py.)
 jax.config.update("jax_enable_x64", True)
+
+#: JAX's persistent compile cache.  ``$JAX_COMPILATION_CACHE_DIR`` wins
+#: (JAX reads it itself); otherwise one fixed directory inside the
+#: checkout — fixed, because the path is part of every entry's key.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), os.pardir, os.pardir, os.pardir, ".jax_cache"))
+if not os.environ.get(CACHE_DIR_ENV):
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
@@ -51,7 +62,8 @@ from . import wtypes as wt  # noqa: E402
 from .analysis import bounds as _bounds  # noqa: E402
 from .backend.jaxgen import emit_program  # noqa: E402
 from .backend.values import WDict, WGroup, WVec  # noqa: E402
-from .errors import CapacityError, ResourceError  # noqa: E402
+from .errors import (  # noqa: E402
+    CapacityError, KernelCompileError, ResourceError, WeldError)
 from .lazy import Program  # noqa: E402
 from .passes import loop_count, optimize as run_passes  # noqa: E402
 
@@ -297,7 +309,7 @@ def lower(
         # takes effect, which a cached executable would otherwise defeat
         from ..kernels import ops as _kops
 
-        kernel_impl = _kops.DEFAULT_IMPL
+        kernel_impl = _kops.default_impl()
     return _lower(prog, optimize, memory_limit, passes, mode, kernel_impl)
 
 
@@ -453,9 +465,32 @@ def _jit_stage(low: LoweredProgram, expr: ir.Expr, stats: dict,
                           low.memory_limit, kernel_impl=low.kernel_impl)
         jitted = jax.jit(fn)
         # trigger tracing+compilation now so compile_ms is honest
-        _ = jitted.lower(*low.arrays).compile()
+        try:
+            _ = jitted.lower(*low.arrays).compile()
+        except WeldError:
+            raise
+        except Exception as e:
+            if low.kernelize_on and _MOSAIC_RE.search(str(e)):
+                raise _kernel_compile_error(e, stats, low.kernel_impl) from e
+            raise
     stats["compile_ms"] = optimize_ms + (time.perf_counter() - t0) * 1e3
     return jitted
+
+
+#: how the TPU kernel compiler (or Pallas' lowering into it) names itself
+#: in the errors it raises at ``jit(...).lower()`` / ``.compile()``.
+_MOSAIC_RE = re.compile(r"Mosaic|Pallas|pallas_call")
+
+
+def _kernel_compile_error(e: Exception, stats: dict,
+                          impl: Optional[str]) -> KernelCompileError:
+    """Type a kernel compiler failure of the whole program: it names
+    the offending kernel when the plan routed exactly one."""
+    routed = sorted(stats.get("kernelplan", {}).get("routed", {}))
+    return KernelCompileError(
+        f"kernel compile failed (impl={impl}, routed kernels: "
+        f"{', '.join(routed) or 'none'}): {type(e).__name__}: {e}",
+        kernel=routed[0] if len(routed) == 1 else None, impl=impl)
 
 
 def _compile_handle(low: LoweredProgram) -> Tuple[object, dict, bool]:
@@ -627,7 +662,7 @@ def compile_and_run(
     if kernelize_on and kernel_impl is None:
         from ..kernels import ops as _kops
 
-        kernel_impl = _kops.DEFAULT_IMPL
+        kernel_impl = _kops.default_impl()
     with obs.span("weld.evaluate", kernelize=mode, impl=kernel_impl) as root:
         # the recovery ladder owns retries: capacity poison regrows
         # builder capacities then degrades to the generic lowering;

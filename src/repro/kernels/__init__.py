@@ -2,7 +2,7 @@
 
 Each kernel <name>.py carries a pl.pallas_call with explicit BlockSpec
 VMEM tiling; ops.py holds the jit'd public wrappers; ref.py the pure-jnp
-oracles.  All kernels validate in interpret=True mode on CPU (the dry-run
+oracles.  All kernels validate in interpret mode on CPU (the dry-run
 and CPU benchmarks use the ref path; the kernels are the TPU target).
 
 Kernel inventory and the Weld construct each one lowers:

@@ -28,6 +28,11 @@ BLOCK = 8 * 1024
 BLOCK_CANDIDATES = (1024, 8 * 1024, 32 * 1024)
 
 
+def _row_sum(v):
+    """Sum of a 1-D block as a (1, 1) tile."""
+    return jnp.sum(v[None, :], axis=1, keepdims=True)
+
+
 def _kernel(x_ref, pred_ref, o_ref):
     i = pl.program_id(0)
 
@@ -37,12 +42,13 @@ def _kernel(x_ref, pred_ref, o_ref):
 
     x = x_ref[...]
     keep = pred_ref[...]
-    contrib = jnp.sum(jnp.where(keep, x, jnp.zeros_like(x)))
-    o_ref[...] += contrib[None, None]
+    # reduce to (1, 1), not to a scalar: Mosaic lowers a scalar result
+    # through jnp, which would widen an int32 sum to 64 bits under x64
+    o_ref[...] += _row_sum(jnp.where(keep, x, jnp.zeros_like(x)))
 
 
 def filter_reduce_sum(x: jax.Array, pred: jax.Array, *,
-                      block: int = BLOCK, interpret: bool = True) -> jax.Array:
+                      block: int = BLOCK, interpret: bool) -> jax.Array:
     """sum(x[pred]) in one pass.  x: (n,) float; pred: (n,) bool.
     n is padded to a block multiple with pred=False."""
     n = x.shape[0]
@@ -87,7 +93,7 @@ def _kernel_multi(vals_ref, pred_ref, o_ref):
 
 def filter_reduce_sum_multi(vals: jax.Array, pred: jax.Array, *,
                             block: int = BLOCK,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool) -> jax.Array:
     """Row-wise predicated sums: vals (A, n), pred (n,) -> (A,) where
     out[a] = sum(vals[a][pred]).  One pass; the predicate and the column
     tiles are loaded once for all A aggregates."""
@@ -126,12 +132,12 @@ def _kernel_fused_pred(cols_ref, lo_ref, hi_ref, val_ref, o_ref):
     hi = hi_ref[...]              # (K, 1)
     keep = jnp.all((cols >= lo) & (cols < hi), axis=0)   # (B,)
     v = val_ref[...]
-    o_ref[...] += jnp.sum(jnp.where(keep, v, jnp.zeros_like(v)))[None, None]
+    o_ref[...] += _row_sum(jnp.where(keep, v, jnp.zeros_like(v)))
 
 
 def filter_reduce_q6(cols: jax.Array, lo: jax.Array, hi: jax.Array,
                      val: jax.Array, *, block: int = BLOCK,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """cols: (K, n) predicate columns; lo/hi: (K,) bounds; val: (n,).
     Computes sum(val[all(lo<=cols<hi)]) in a single fused pass."""
     k, n = cols.shape

@@ -74,7 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, group: int = 1, scale=None,
                     bq: int = 512, bk: int = 512,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: (H, Sq, D); k/v: (H//group, Skv, D).  Returns (H, Sq, D).
 
     Causal alignment assumes q positions are the LAST Sq positions of the

@@ -50,7 +50,7 @@ def _kernel(p_ref, g_ref, m_ref, v_ref, lr_ref, t_ref,
 def adamw_update(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
                  lr, step, *, b1: float = 0.9, b2: float = 0.999,
                  eps: float = 1e-8, wd: float = 0.01,
-                 block: int = BLOCK, interpret: bool = True):
+                 block: int = BLOCK, interpret: bool):
     """One fused AdamW step over a flat f32 parameter shard.
     Returns (p_new, m_new, v_new)."""
     n = p.shape[0]

@@ -2,17 +2,17 @@
 
 Given a dict in the backend's sorted-front-packed column layout
 (``WDict``: keys ascending for the first ``count`` slots), find each
-query key's slot and whether it exists.  The TPU-native strategy mirrors
-``segment_reduce``: instead of a divergent binary search per lane, each
-query block builds a **one-hot membership matrix** against the whole
-VMEM-resident key tile
+query key's slot and whether it exists.  The TPU-native strategy
+replaces a divergent binary search per lane with a **broadcast
+membership sweep**: the table's keys sit in SMEM, and each query block
+(a VMEM vector tile) is compared against them one key at a time,
 
-    hits[B, C] = (queries[:, None] == table[None, :]) & (iota_C < count)
+    hit_c = (queries == table[c]);  pos = where(hit_c, c, pos)
 
-and reduces it on the VPU — ``found = any(hits, axis=1)``,
-``pos = argmax(hits, axis=1)`` (keys are unique, so at most one lane
-matches).  C is bounded by the dict capacity (<= ``hash_table.MAX_CAP``)
-so the comparison tile fits VMEM alongside the query block.
+for ``c < count`` — every compare is a full-width VPU op against a
+scalar broadcast, no gather, no relayout.  Keys are unique, so at most
+one ``c`` hits a query.  The sweep's length is the dict's live count,
+bounded by its capacity (<= ``hash_table.MAX_CAP``).
 
 The value gather itself happens outside the kernel (``vals[pos]``): the
 positions serve any value dtype/struct without specializing the kernel.
@@ -21,18 +21,18 @@ ONE launch for every output column — inner joins front-pack by the
 found mask, left joins keep every row and select per-dtype fills where
 ``found`` is false, anti joins front-pack by its negation — all from
 the same ``(pos, found)`` pair (``kernelplan.registry``,
-``_exec_hash_probe_fused``).  Multi-column keys arrive pre-packed (32
-bits per column) in the same i64 key space the build side uses.
+``_exec_hash_probe_fused``).
 
-Contract (shared with ``ref.dict_probe``): queries and table keys live
-in the packed key space; returns ``(pos, found)`` with ``pos`` int32,
-zeroed where not found.
+Contract (shared with ``ref.dict_probe``): queries and table keys share
+one key space (int32 for a single key column of at most 32 bits, else
+the packed int64 space — ref/interpret only); returns ``(pos, found)``
+with ``pos`` int32, zeroed where not found.
 
-``group_probe`` is the m:n-join variant: the SAME hits tile also
-one-hot-gathers each matching group's fan-out (CSR ``offsets`` diffs),
-so membership, slot positions, and the expansion's match-count pass
-are one launch; the expansion itself (exclusive scan + repeat/gather)
-runs outside, shared by every output column
+``group_probe`` is the m:n-join variant: the SAME sweep also selects
+each matching group's fan-out (CSR ``offsets`` diffs, in SMEM beside the
+keys), so membership, slot positions, and the expansion's match-count
+pass are one launch; the expansion itself (exclusive scan +
+repeat/gather) runs outside, shared by every output column
 (``kernelplan.registry._exec_group_probe``).
 """
 from __future__ import annotations
@@ -42,47 +42,74 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_N = 512
-#: autotune grid for the query block: the hits tile is block x capacity,
-#: so small blocks keep large-capacity dicts inside VMEM.
-BLOCK_CANDIDATES = (128, 256, 512, 1024)
-
-
-def _kernel(q_ref, keys_ref, cnt_ref, pos_ref, found_ref, *, cap: int):
-    q = q_ref[...]                               # (B,)
-    keys = keys_ref[...]                         # (C,)
-    cnt = cnt_ref[0, 0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], cap), 1)
-    hits = (q[:, None] == keys[None, :]) & (iota < cnt)
-    found = jnp.any(hits, axis=1)
-    pos = jnp.argmax(hits, axis=1).astype(jnp.int32)
-    found_ref[...] = found
-    pos_ref[...] = jnp.where(found, pos, jnp.int32(0))
+BLOCK_N = 4096
+#: autotune grid for the query block: multiples of 1024 (the TPU tiling of
+#: a 1-D 32-bit operand); bigger blocks amortize the scalar sweep over
+#: more query lanes.
+BLOCK_CANDIDATES = (1024, 4096, 8192)
 
 
-def _group_kernel(q_ref, keys_ref, sizes_ref, cnt_ref, pos_ref, found_ref,
-                  size_ref, *, cap: int):
-    q = q_ref[...]                               # (B,)
-    keys = keys_ref[...]                         # (C,)
-    sizes = sizes_ref[...]                       # (C,) group fan-outs
-    cnt = cnt_ref[0, 0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], cap), 1)
-    hits = (q[:, None] == keys[None, :]) & (iota < cnt)
-    found = jnp.any(hits, axis=1)
-    pos = jnp.argmax(hits, axis=1).astype(jnp.int32)
-    # one-hot gather of the matching group's size on the VPU: the SAME
-    # hits tile serves membership, position, and the match-count pass of
-    # the m:n expansion — one launch, three outputs
-    size = jnp.sum(jnp.where(hits, sizes[None, :], jnp.int32(0)), axis=1)
-    found_ref[...] = found
-    pos_ref[...] = jnp.where(found, pos, jnp.int32(0))
-    size_ref[...] = jnp.where(found, size.astype(jnp.int32), jnp.int32(0))
+def _sweep(q, cnt, keys_ref, sizes_ref=None):
+    """(pos, size) per query lane; pos is -1 on a miss."""
+    def body(c, carry):
+        pos, size = carry
+        hit = q == keys_ref[c]
+        pos = jnp.where(hit, c, pos)
+        if sizes_ref is not None:
+            size = jnp.where(hit, sizes_ref[c], size)
+        return pos, size
+
+    init = (jnp.full(q.shape, -1, jnp.int32), jnp.zeros(q.shape, jnp.int32))
+    return jax.lax.fori_loop(0, cnt, body, init)
+
+
+def _kernel(cnt_ref, keys_ref, q_ref, pos_ref, found_ref):
+    pos, _ = _sweep(q_ref[...], cnt_ref[0, 0], keys_ref)
+    found_ref[...] = (pos >= 0).astype(jnp.int32)
+    pos_ref[...] = jnp.maximum(pos, 0)
+
+
+def _group_kernel(cnt_ref, keys_ref, sizes_ref, q_ref, pos_ref, found_ref,
+                  size_ref):
+    pos, size = _sweep(q_ref[...], cnt_ref[0, 0], keys_ref, sizes_ref)
+    found_ref[...] = (pos >= 0).astype(jnp.int32)
+    pos_ref[...] = jnp.maximum(pos, 0)
+    size_ref[...] = size
+
+
+def _probe_call(kernel, tables, queries, count, block: int, n_out: int,
+                interpret: bool):
+    """Launch one sweep kernel: ``tables`` ride whole in SMEM, queries
+    stream in ``block``-lane VMEM tiles; returns ``n_out`` int32 columns
+    trimmed to the query count."""
+    n = queries.shape[0]
+    npad = (block - n % block) % block
+    if npad:
+        queries = jnp.pad(queries, (0, npad))
+    # the sweep reads SMEM at every c < count: clamp a poisoned or
+    # oversized count into the table
+    cnt = jnp.clip(jnp.asarray(count, jnp.int32), 0,
+                   tables[0].shape[0]).reshape(1, 1)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    lane = pl.BlockSpec((block,), lambda i: (i,))
+    col = jax.ShapeDtypeStruct((queries.shape[0],), jnp.int32)
+    outs = pl.pallas_call(
+        kernel,
+        out_shape=(col,) * n_out,
+        grid=(queries.shape[0] // block,),
+        in_specs=[smem((1, 1), lambda i: (0, 0))]
+        + [smem(t.shape, lambda i: (0,)) for t in tables] + [lane],
+        out_specs=(lane,) * n_out,
+        interpret=interpret,
+    )(cnt, *tables, queries)
+    return [o[:n] for o in outs]
 
 
 def group_probe(table_keys: jax.Array, offsets: jax.Array, count,
                 queries: jax.Array, *, block: int = BLOCK_N,
-                interpret: bool = True):
+                interpret: bool):
     """(pos, found, sizes) per query against a groupbuilder's sorted
     key column + CSR offsets — the membership AND match-count pass of
     the m:n join expansion in ONE launch (``sizes`` is 0 on a miss).
@@ -93,63 +120,18 @@ def group_probe(table_keys: jax.Array, offsets: jax.Array, count,
         z = jnp.zeros((n,), jnp.int32)
         return z, jnp.zeros((n,), bool), z
     sizes = (offsets[1:] - offsets[:-1]).astype(jnp.int32)
-    npad = (block - n % block) % block
-    if npad:
-        queries = jnp.pad(queries, (0, npad))
-    grid = (queries.shape[0] // block,)
-    cnt = jnp.asarray(count, jnp.int32).reshape(1, 1)
-    pos, found, size = pl.pallas_call(
-        functools.partial(_group_kernel, cap=cap),
-        out_shape=(
-            jax.ShapeDtypeStruct((queries.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((queries.shape[0],), jnp.bool_),
-            jax.ShapeDtypeStruct((queries.shape[0],), jnp.int32),
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((cap,), lambda i: (0,)),
-            pl.BlockSpec((cap,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ),
-        interpret=interpret,
-    )(queries.astype(jnp.int64), table_keys.astype(jnp.int64), sizes, cnt)
-    return pos[:n], found[:n], size[:n]
+    pos, found, size = _probe_call(
+        _group_kernel, (table_keys.astype(queries.dtype), sizes), queries,
+        count, block, 3, interpret)
+    return pos, found.astype(bool), size
 
 
 def dict_probe(table_keys: jax.Array, count, queries: jax.Array, *,
-               block: int = BLOCK_N, interpret: bool = True):
+               block: int = BLOCK_N, interpret: bool):
     """pos/found per query against sorted-front-packed dict keys."""
-    cap = table_keys.shape[0]
     n = queries.shape[0]
-    if n == 0:
-        return jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool)
-    npad = (block - n % block) % block
-    if npad:
-        queries = jnp.pad(queries, (0, npad))
-    grid = (queries.shape[0] // block,)
-    cnt = jnp.asarray(count, jnp.int32).reshape(1, 1)
-    pos, found = pl.pallas_call(
-        functools.partial(_kernel, cap=cap),
-        out_shape=(
-            jax.ShapeDtypeStruct((queries.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((queries.shape[0],), jnp.bool_),
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((cap,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ),
-        interpret=interpret,
-    )(queries.astype(jnp.int64), table_keys.astype(jnp.int64), cnt)
-    return pos[:n], found[:n]
+    if n == 0 or table_keys.shape[0] == 0:
+        return jnp.zeros((n,), jnp.int32), jnp.zeros((n,), bool)
+    pos, found = _probe_call(_kernel, (table_keys.astype(queries.dtype),),
+                             queries, count, block, 2, interpret)
+    return pos, found.astype(bool)

@@ -31,7 +31,7 @@ BLOCK_CANDIDATES = (1024, 8 * 1024, 32 * 1024)
 
 
 def map_elementwise(fn: Callable, arrays: Sequence[jax.Array], *,
-                    block: int = BLOCK, interpret: bool = True) -> jax.Array:
+                    block: int = BLOCK, interpret: bool) -> jax.Array:
     """out[i] = fn(a1[i], ..., ak[i]) for equal-length 1-D arrays.
 
     Inputs are padded to a block multiple; ``fn`` must be total on the

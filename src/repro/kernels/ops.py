@@ -4,9 +4,16 @@
   * "pallas"    — the Pallas kernel compiled for the accelerator
   * "interpret" — the Pallas kernel body interpreted on CPU (validation)
   * "ref"       — the pure-jnp oracle (CPU benchmarks, dry-run lowering)
-Default on this CPU container is "ref"; on TPU the launcher flips the
-default to "pallas".  Resolution happens OUTSIDE jit so flipping the
-default always takes effect (impl is a static argument of the inner jit).
+An omitted ``impl`` resolves from the backend, once per process:
+"pallas" when JAX's default backend is a TPU, "ref" elsewhere.  An
+explicit ``impl=`` always wins.  Resolution happens OUTSIDE jit (impl is
+a static argument of the inner jit).
+
+Every Pallas kernel traces in 32-bit mode when its operands are 32-bit:
+the Weld runtime runs the process with x64 on, and Mosaic lowers no
+64-bit index map, loop counter or scalar.  64-bit operands are accepted
+by the ref and interpret paths only — the kernel planner rejects them for
+"pallas" before anything is staged.
 
 Block sizes are tunable: every entry takes an optional block override
 (``block=``, or ``bm``/``bn``/``bk`` for the matmul) resolved to the
@@ -17,10 +24,12 @@ through these knobs; the ref oracle ignores them by construction.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Literal, Optional
 
 import jax
+import jax.numpy as jnp
 
 from . import filter_reduce as _fr
 from . import flash_attention as _fa
@@ -35,7 +44,17 @@ from . import tiled_matmul as _tm
 
 Impl = Literal["pallas", "interpret", "ref"]
 
-DEFAULT_IMPL: Impl = "ref"
+#: the process default, resolved from the backend on first use.
+DEFAULT_IMPL: Optional[Impl] = None
+
+
+def default_impl() -> Impl:
+    """The kernel path an omitted ``impl`` takes: "pallas" on a TPU
+    backend, "ref" elsewhere."""
+    global DEFAULT_IMPL
+    if DEFAULT_IMPL is None:
+        DEFAULT_IMPL = "pallas" if jax.default_backend() == "tpu" else "ref"
+    return DEFAULT_IMPL
 
 
 def set_default_impl(impl: Impl) -> None:
@@ -44,7 +63,16 @@ def set_default_impl(impl: Impl) -> None:
 
 
 def _resolve(impl: Optional[str]) -> str:
-    return DEFAULT_IMPL if impl is None else impl
+    return default_impl() if impl is None else impl
+
+
+def _kernel_mode(*operands):
+    """x64 mode to trace a Pallas kernel in: off when every operand is
+    32-bit or narrower (what Mosaic lowers), the process mode otherwise."""
+    if any(jnp.dtype(a.dtype).itemsize > 4
+           for a in jax.tree_util.tree_leaves(operands)):
+        return contextlib.nullcontext()
+    return jax.enable_x64(False)
 
 
 # -- filter+reduce -------------------------------------------------------------
@@ -54,8 +82,9 @@ def _resolve(impl: Optional[str]) -> str:
 def _frs(x, pred, impl, block):
     if impl == "ref":
         return _ref.filter_reduce_sum(x, pred)
-    return _fr.filter_reduce_sum(x, pred, block=block,
-                                 interpret=(impl == "interpret"))
+    with _kernel_mode(x):
+        return _fr.filter_reduce_sum(x, pred, block=block,
+                                     interpret=(impl == "interpret"))
 
 
 def filter_reduce_sum(x, pred, impl: Optional[Impl] = None,
@@ -67,8 +96,9 @@ def filter_reduce_sum(x, pred, impl: Optional[Impl] = None,
 def _frsm(vals, pred, impl, block):
     if impl == "ref":
         return _ref.filter_reduce_sum_multi(vals, pred)
-    return _fr.filter_reduce_sum_multi(vals, pred, block=block,
-                                       interpret=(impl == "interpret"))
+    with _kernel_mode(vals):
+        return _fr.filter_reduce_sum_multi(vals, pred, block=block,
+                                           interpret=(impl == "interpret"))
 
 
 def filter_reduce_sum_multi(vals, pred, impl: Optional[Impl] = None,
@@ -82,8 +112,9 @@ def filter_reduce_sum_multi(vals, pred, impl: Optional[Impl] = None,
 def _frq6(cols, lo, hi, val, impl, block):
     if impl == "ref":
         return _ref.filter_reduce_q6(cols, lo, hi, val)
-    return _fr.filter_reduce_q6(cols, lo, hi, val, block=block,
-                                interpret=(impl == "interpret"))
+    with _kernel_mode(cols, lo, hi, val):
+        return _fr.filter_reduce_q6(cols, lo, hi, val, block=block,
+                                    interpret=(impl == "interpret"))
 
 
 def filter_reduce_q6(cols, lo, hi, val, impl: Optional[Impl] = None,
@@ -99,8 +130,9 @@ def filter_reduce_q6(cols, lo, hi, val, impl: Optional[Impl] = None,
 def _ss(seg_ids, vals, num_segments, impl, block):
     if impl == "ref":
         return _ref.segment_sum(seg_ids, vals, num_segments)
-    return _sr.segment_sum(seg_ids, vals, num_segments, block=block,
-                           interpret=(impl == "interpret"))
+    with _kernel_mode(vals):
+        return _sr.segment_sum(seg_ids.astype(jnp.int32), vals, num_segments,
+                               block=block, interpret=(impl == "interpret"))
 
 
 def segment_sum(seg_ids, vals, num_segments: int,
@@ -116,8 +148,10 @@ def segment_sum(seg_ids, vals, num_segments: int,
 def _ssv(seg_ids, vals, num_segments, impl, block):
     if impl == "ref":
         return _ref.segment_sum_vectors(seg_ids, vals, num_segments)
-    return _sr.segment_sum_vectors(seg_ids, vals, num_segments, block=block,
-                                   interpret=(impl == "interpret"))
+    with _kernel_mode(vals):
+        return _sr.segment_sum_vectors(seg_ids.astype(jnp.int32), vals,
+                                       num_segments, block=block,
+                                       interpret=(impl == "interpret"))
 
 
 def segment_sum_vectors(seg_ids, vals, num_segments: int,
@@ -127,7 +161,7 @@ def segment_sum_vectors(seg_ids, vals, num_segments: int,
     if num_segments > _sr.MAX_K:
         impl = "ref"
     return _ssv(seg_ids, vals, num_segments=num_segments, impl=impl,
-                block=block or 256)
+                block=block or _sr.BLOCK_N)
 
 
 # -- dict build / probe (hash-join route) -----------------------------------------
@@ -137,15 +171,17 @@ def segment_sum_vectors(seg_ids, vals, num_segments: int,
 def _hts(keys, cap_table, impl, block):
     if impl == "ref":
         return _ref.hash_to_slot(keys, cap_table)
-    return _ht.hash_to_slot(keys, cap_table, block=block,
-                            interpret=(impl == "interpret"))
+    with _kernel_mode(keys):
+        return _ht.hash_to_slot(keys, cap_table, block=block,
+                                interpret=(impl == "interpret"))
 
 
 def hash_to_slot(keys, cap_table: int, impl: Optional[Impl] = None,
                  block: Optional[int] = None):
-    """Open-addressing slot assignment for i64 (packed) keys; rows equal
-    to ``hash_table.EMPTY`` park at slot ``cap_table``.  Returns
-    ``(slots, table_keys, used)`` — see kernels/hash_table.py."""
+    """Open-addressing slot assignment for int32 or packed int64 keys;
+    rows equal to ``hash_table.empty_of(keys.dtype)`` park at slot
+    ``cap_table``.  Returns ``(slots, table_keys, used)`` — see
+    kernels/hash_table.py."""
     return _hts(keys, cap_table=cap_table, impl=_resolve(impl),
                 block=block or _ht.BLOCK_N)
 
@@ -154,15 +190,16 @@ def hash_to_slot(keys, cap_table: int, impl: Optional[Impl] = None,
 def _dp(table_keys, count, queries, impl, block):
     if impl == "ref":
         return _ref.dict_probe(table_keys, count, queries)
-    return _hp.dict_probe(table_keys, count, queries, block=block,
-                          interpret=(impl == "interpret"))
+    with _kernel_mode(table_keys, queries):
+        return _hp.dict_probe(table_keys, count.astype(jnp.int32), queries,
+                              block=block, interpret=(impl == "interpret"))
 
 
 def dict_probe(table_keys, count, queries, impl: Optional[Impl] = None,
                block: Optional[int] = None):
     """(pos, found) per query against a sorted-front-packed dict key
     column; ``pos`` is zeroed where not found."""
-    return _dp(table_keys, count, queries, impl=_resolve(impl),
+    return _dp(table_keys, jnp.asarray(count), queries, impl=_resolve(impl),
                block=block or _hp.BLOCK_N)
 
 
@@ -173,13 +210,14 @@ def dict_probe(table_keys, count, queries, impl: Optional[Impl] = None,
 def _gbd(keys, capacity, impl, block):
     if impl == "ref":
         return _ref.group_build(keys, capacity)
-    return _gb.group_build(keys, capacity, block=block,
-                           interpret=(impl == "interpret"))
+    with _kernel_mode(keys):
+        return _gb.group_build(keys, capacity, block=block,
+                               interpret=(impl == "interpret"))
 
 
 def group_build(keys, capacity: int, impl: Optional[Impl] = None,
                 block: Optional[int] = None):
-    """CSR group build over i64 (packed) keys: rows with equal keys share
+    """CSR group build over int32 / packed int64 keys: rows with equal keys share
     an ascending-key compact slot.  Returns ``(cslots, offsets, used)``
     — see kernels/group_build.py for the contract."""
     return _gbd(keys, capacity=capacity, impl=_resolve(impl),
@@ -190,8 +228,10 @@ def group_build(keys, capacity: int, impl: Optional[Impl] = None,
 def _gpr(table_keys, offsets, count, queries, impl, block):
     if impl == "ref":
         return _ref.group_probe(table_keys, offsets, count, queries)
-    return _hp.group_probe(table_keys, offsets, count, queries, block=block,
-                           interpret=(impl == "interpret"))
+    with _kernel_mode(table_keys, queries):
+        return _hp.group_probe(table_keys, offsets.astype(jnp.int32),
+                               count.astype(jnp.int32), queries, block=block,
+                               interpret=(impl == "interpret"))
 
 
 def group_probe(table_keys, offsets, count, queries,
@@ -199,8 +239,8 @@ def group_probe(table_keys, offsets, count, queries,
     """(pos, found, sizes) per query against a groupbuilder's sorted key
     column + CSR offsets — membership and the m:n expansion's
     match-count pass in one launch; ``sizes`` is 0 where not found."""
-    return _gpr(table_keys, offsets, count, queries, impl=_resolve(impl),
-                block=block or _hp.BLOCK_N)
+    return _gpr(table_keys, offsets, jnp.asarray(count), queries,
+                impl=_resolve(impl), block=block or _hp.BLOCK_N)
 
 
 # -- fused adamw ----------------------------------------------------------------
@@ -212,8 +252,9 @@ def _adamw(p, g, m, v, lr, step, b1, b2, eps, wd, impl, block):
     kw = dict(b1=b1, b2=b2, eps=eps, wd=wd)
     if impl == "ref":
         return _ref.adamw_update(p, g, m, v, lr, step, **kw)
-    return _aw.adamw_update(p, g, m, v, lr, step, block=block,
-                            interpret=(impl == "interpret"), **kw)
+    with _kernel_mode(p, g, m, v):
+        return _aw.adamw_update(p, g, m, v, lr, step, block=block,
+                                interpret=(impl == "interpret"), **kw)
 
 
 def adamw_update(p, g, m, v, lr, step, b1=0.9, b2=0.999, eps=1e-8, wd=0.01,
@@ -229,8 +270,9 @@ def adamw_update(p, g, m, v, lr, step, b1=0.9, b2=0.999, eps=1e-8, wd=0.01,
 def _mm(a, b, impl, bm, bn, bk):
     if impl == "ref":
         return _ref.tiled_matmul(a, b)
-    return _tm.tiled_matmul(a, b, bm=bm, bn=bn, bk=bk,
-                            interpret=(impl == "interpret"))
+    with _kernel_mode(a, b):
+        return _tm.tiled_matmul(a, b, bm=bm, bn=bn, bk=bk,
+                                interpret=(impl == "interpret"))
 
 
 def matmul(a, b, impl: Optional[Impl] = None, bm: Optional[int] = None,
@@ -253,8 +295,9 @@ def map_elementwise(fn, arrays, impl: Optional[Impl] = None,
     impl = _resolve(impl)
     if impl == "ref":
         return _ref.map_elementwise(fn, arrays)
-    return _mc.map_elementwise(fn, arrays, block=block or _mc.BLOCK,
-                               interpret=(impl == "interpret"))
+    with _kernel_mode(arrays):
+        return _mc.map_elementwise(fn, arrays, block=block or _mc.BLOCK,
+                                   interpret=(impl == "interpret"))
 
 
 # -- attention --------------------------------------------------------------------
@@ -269,8 +312,10 @@ def _attn(q, k, v, causal, group, scale, chunk, unroll, impl):
         return _ref.chunked_attention(q, k, v, causal=causal, group=group,
                                       scale=scale, chunk=chunk,
                                       unroll=unroll)
-    return _fa.flash_attention(q, k, v, causal=causal, group=group,
-                               scale=scale, interpret=(impl == "interpret"))
+    with _kernel_mode(q, k, v):
+        return _fa.flash_attention(q, k, v, causal=causal, group=group,
+                                   scale=scale,
+                                   interpret=(impl == "interpret"))
 
 
 def attention(q, k, v, causal: bool = True, group: int = 1, scale=None,

@@ -36,16 +36,16 @@ def hash_to_slot(keys, cap_table):
     Slot numbering is ascending-key compact ids (the Pallas kernel uses
     hash positions instead — only the slots/table CONTRACT is shared,
     see kernels/hash_table.py)."""
-    from .hash_table import EMPTY
+    from .hash_table import empty_of
 
+    empty = empty_of(keys.dtype)
     n = keys.shape[0]
     if n == 0:
         return (jnp.zeros((0,), jnp.int32),
-                jnp.full((cap_table,), EMPTY, jnp.int64),
+                jnp.full((cap_table,), empty, keys.dtype),
                 jnp.zeros((), jnp.int32))
-    keys = keys.astype(jnp.int64)
-    valid = keys != EMPTY
-    big = jnp.iinfo(jnp.int64).max
+    valid = keys != empty
+    big = jnp.iinfo(keys.dtype).max
     pk = jnp.where(valid, keys, big)
     order = jnp.argsort(pk, stable=True)
     sk = pk[order]
@@ -55,9 +55,9 @@ def hash_to_slot(keys, cap_table):
     seg = jnp.where(sval & (seg < cap_table), seg, cap_table)
     slots = jnp.zeros((n,), jnp.int32).at[order].set(seg)
     used = is_new.sum().astype(jnp.int32)
-    table = jnp.full((cap_table,), EMPTY, jnp.int64).at[
+    table = jnp.full((cap_table,), empty, keys.dtype).at[
         jnp.where(is_new, seg, cap_table)
-    ].set(jnp.where(is_new, sk, EMPTY), mode="drop")
+    ].set(jnp.where(is_new, sk, empty), mode="drop")
     return slots, table, used
 
 
@@ -85,7 +85,7 @@ def group_build(keys, capacity):
     boundaries over those slots; ``used`` counts distinct valid keys
     (``used > capacity`` = overflow, callers poison — the contract
     shared with kernels/group_build.py)."""
-    from .hash_table import EMPTY
+    from .hash_table import empty_of
 
     cap = int(capacity)
     n = keys.shape[0]
@@ -93,9 +93,8 @@ def group_build(keys, capacity):
         return (jnp.zeros((0,), jnp.int32),
                 jnp.zeros((cap + 1,), jnp.int32),
                 jnp.zeros((), jnp.int32))
-    keys = keys.astype(jnp.int64)
-    valid = keys != EMPTY
-    big = jnp.iinfo(jnp.int64).max
+    valid = keys != empty_of(keys.dtype)
+    big = jnp.iinfo(keys.dtype).max
     pk = jnp.where(valid, keys, big)
     order = jnp.argsort(pk, stable=True)
     sk = pk[order]

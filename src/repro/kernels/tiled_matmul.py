@@ -41,7 +41,7 @@ def _kernel(a_ref, b_ref, o_ref):
 
 
 def tiled_matmul(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
-                 bk: int = 512, interpret: bool = True) -> jax.Array:
+                 bk: int = 512, interpret: bool) -> jax.Array:
     """C = A @ B with explicit VMEM tiling.  Shapes padded to tiles."""
     m, k = a.shape
     k2, n = b.shape

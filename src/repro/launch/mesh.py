@@ -9,6 +9,14 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the model stack pins parameter shardings and leaves every
+    # activation's sharding to the partitioner (explicit axes would demand
+    # an out_sharding on each contraction over a sharded dimension)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     'pod' axis (used for hierarchical data parallelism / optional PP)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh(dp: Optional[int] = None, tp: int = 1):
@@ -26,7 +34,7 @@ def make_local_mesh(dp: Optional[int] = None, tp: int = 1):
     if dp is None:
         dp = n // tp
     assert dp * tp <= n, f"need {dp * tp} devices, have {n}"
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return _mesh((dp, tp), ("data", "model"))
 
 
 def mesh_axis_size(mesh, name) -> int:

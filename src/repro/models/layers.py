@@ -18,6 +18,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..kernels import ops as kops
 
@@ -97,7 +98,12 @@ def embedding_specs():
 
 
 def embed(params, tokens):
-    return jnp.take(params["table"], tokens, axis=0)
+    # the table may be sharded over its vocab rows, so the gather's output
+    # sharding is ambiguous: the gathered rows follow the tokens' sharding
+    table = params["table"]
+    out = NamedSharding(jax.typeof(table).sharding.mesh,
+                        PartitionSpec(*jax.typeof(tokens).sharding.spec, None))
+    return table.at[tokens].get(out_sharding=out)
 
 
 def unembed(params, x):
@@ -203,10 +209,13 @@ def attention_apply(params, x, cfg, positions=None, causal: bool = True,
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     chunk = min(cfg.attn_chunk, kh.shape[2])
+    # the jnp path on every backend: the Pallas flash-attention kernel does
+    # not lower for the TPU (its (1, bq) row-statistics blocks break
+    # Mosaic's (8, 128) tiling)
     out = jax.vmap(
         lambda qq, kk, vv: kops.attention(
             qq, kk, vv, causal=causal, group=group, chunk=chunk,
-            unroll=cfg.scan_unroll,
+            unroll=cfg.scan_unroll, impl="ref",
         )
     )(qh, kh, vh)
     out = out.transpose(0, 2, 1, 3)  # (B,T,H,D)

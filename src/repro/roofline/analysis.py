@@ -16,13 +16,40 @@ from __future__ import annotations
 import re
 from typing import Dict, Optional
 
-#: TPU v5e hardware constants (per chip)
-HW_V5E = {
-    "peak_flops_bf16": 197e12,   # FLOP/s
-    "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link
-    "hbm_bytes": 16 * 1024 ** 3,
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google
+#: Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+#: 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (``ici_bw`` is
+#: one of its four links).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,   # FLOP/s
+        "hbm_bw": 819e9,             # B/s
+        "ici_bw": 50e9,              # B/s per link
+        "hbm_bytes": 16 * 1024 ** 3,
+    },
 }
+
+#: the chip the repo targets: what a host without a TPU prices for.
+TARGET_KIND = "TPU v5 lite"
+HW_V5E = PEAKS[TARGET_KIND]
+
+
+def device_peaks() -> tuple:
+    """``(device_kind, peaks, is_target)`` for the device this process
+    runs on.  A TPU whose kind is not in :data:`PEAKS` is an error, not a
+    default; a host without a TPU prices for :data:`TARGET_KIND` and says
+    so (``is_target``)."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TARGET_KIND, HW_V5E, True
+    if dev.device_kind not in PEAKS:
+        raise KeyError(
+            f"no peak table for TPU device kind {dev.device_kind!r} "
+            f"(known: {sorted(PEAKS)}); add its published peaks to "
+            "roofline.analysis.PEAKS")
+    return dev.device_kind, PEAKS[dev.device_kind], False
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

@@ -168,7 +168,7 @@ def test_compressed_psum_error_feedback():
         import numpy as np
         from functools import partial
         from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.optim.compress import compressed_psum, init_error_buffers
         mesh = jax.make_mesh((8,), ("pod",))
         g = jnp.asarray(np.random.RandomState(0).randn(8, 256),
@@ -185,7 +185,8 @@ def test_compressed_psum_error_feedback():
         errs = []
         for step in range(5):
             synced, err = sync(g, err)
-            rel = float(jnp.linalg.norm(synced[0] - exact)
+            row0 = synced.at[0].get(out_sharding=NamedSharding(mesh, P()))
+            rel = float(jnp.linalg.norm(row0 - exact)
                         / jnp.linalg.norm(exact))
             errs.append(rel)
         print("RESULT " + json.dumps({"rels": errs}))
