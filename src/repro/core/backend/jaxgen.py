@@ -939,10 +939,6 @@ class Emitter:
             return self._measured_kernel_call(x, spec, args, params, fns)
         # per-launch label: device profiles (and jaxpr dumps) name each
         # kernel launch after the IR loop it was planned from
-        from .. import obs
-
-        obs.event("launch.stage", kernel=x.kernel,
-                  n=params.get("n_rows"), impl=self.kernel_impl)
         with jax.named_scope(f"weld.{x.kernel}"):
             return kreg.execute_spec(args=args, params=params, fns=fns,
                                      impl=self.kernel_impl, spec=spec,

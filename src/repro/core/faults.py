@@ -29,7 +29,7 @@ site (``@N`` optional, default 1), then disarms.  Known sites include
 ``kernelplan.registry.execute_spec``), ``dict.build`` / ``group.build``
 (the generic keyed finalize), ``join.capacity`` (weldrel's host-side
 capacity choice), ``decode`` (poison/raise at result decode),
-``measure.replay`` (the traced eager replay), ``autotune.time`` (the
+``measure.replay`` (EXPLAIN ANALYZE's eager replay), ``autotune.time`` (the
 tuner's candidate timer), and ``io.autotune_cache`` / ``io.ledger``
 (best-effort cache/ledger writes).
 

@@ -225,12 +225,8 @@ def tune(spec: "reg.KernelSpec", meta: dict, impl: str,
                 go = spec.make_bench(bench_meta, cand, impl)
                 t = _time_candidate(go)
             except Exception as e:
-                obs.event("autotune.candidate", kernel=spec.name,
-                          skipped=True, **cand)
                 failures.append(f"{cand}: {type(e).__name__}: {e}")
                 continue  # candidate invalid for this shape — skip
-            obs.event("autotune.candidate", kernel=spec.name,
-                      us=round(t * 1e6, 2), **cand)
             if t < best_t:
                 best_params, best_t = cand, t
         tsp.set("best", dict(best_params))

@@ -16,8 +16,8 @@ the exact ``(kernel, dtype, bucket)`` group — a single noisy launch
 must not flip routing.  Disable entirely with ``WELD_CALIBRATE=0``.
 
 Medians are cached in-process keyed on the ledger file's
-``(mtime_ns, size)`` signature, so serving traffic that appends records
-(measured replay) is picked up on the next *cold* compile without
+``(mtime_ns, size)`` signature, so records that EXPLAIN ANALYZE appends
+(its measured replay) are picked up on the next *cold* compile without
 re-parsing the JSONL on every estimate.  Note calibration state is
 deliberately NOT part of the compile-cache key: a cached executable
 keeps serving the plan it was compiled with (compile amortization wins

@@ -55,7 +55,6 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Tuple
 
 from .. import ir
-from .. import obs as _obs
 from .. import wtypes as wt
 from ..backend.jaxgen import match_group_probe as _group_probe_shape
 from . import cost as _cost
@@ -1204,8 +1203,6 @@ def plan_kernels(
                 "kernel": kc.kernel, "routed": False,
                 "why": "quarantined", "kernel_us": 0.0, "jnp_us": 0.0,
             })
-            _obs.event("kernelplan.candidate", kernel=kc.kernel,
-                       n=meta.get("n"), routed=False, why="quarantined")
             return orig
         tpu_why = _tpu_reject(kc) if impl == "pallas" else None
         if tpu_why is not None:
@@ -1216,8 +1213,6 @@ def plan_kernels(
                 "kernel": kc.kernel, "routed": False, "why": tpu_why,
                 "kernel_us": 0.0, "jnp_us": 0.0,
             })
-            _obs.event("kernelplan.candidate", kernel=kc.kernel,
-                       n=meta.get("n"), routed=False, why=tpu_why)
             return orig
         if kc.kernel in ("hash_probe", "group_probe"):
             # the probe sweeps the whole table: an unknown or
@@ -1231,8 +1226,6 @@ def plan_kernels(
         if mode == "auto":
             est = _cost.estimate(reg.get(kc.kernel), meta)
             kplan["costs"].append({"kernel": kc.kernel, **est.as_stats()})
-            _obs.event("kernelplan.candidate", kernel=kc.kernel,
-                       n=meta.get("n"), **est.as_stats())
             if not est.routed:
                 kplan["rejected"][kc.kernel] = (
                     kplan["rejected"].get(kc.kernel, 0) + 1
@@ -1245,8 +1238,6 @@ def plan_kernels(
                 est = _cost.estimate(reg.get(kc.kernel), meta)
             except Exception:
                 est = _cost.REJECT_UNKNOWN
-            _obs.event("kernelplan.candidate", kernel=kc.kernel,
-                       n=meta.get("n"), routed=True, why="mode=always")
         kplan["routed"][kc.kernel] = kplan["routed"].get(kc.kernel, 0) + 1
         stats["kernelize.matched"] += 1
         key = f"kernelize.{kc.kernel}"

@@ -24,6 +24,8 @@ from .tracer import (  # noqa: F401
     event,
     format_tree,
     mark,
+    record,
+    request,
     span,
     spans,
     spans_since,
@@ -32,6 +34,6 @@ from .tracer import (  # noqa: F401
 
 __all__ = [
     "NOOP", "Span", "clear", "disable", "dump_chrome", "enable", "enabled",
-    "event", "format_tree", "ledger", "mark", "span", "spans",
-    "spans_since", "to_chrome",
+    "event", "format_tree", "ledger", "mark", "record", "request", "span",
+    "spans", "spans_since", "to_chrome",
 ]

@@ -1,7 +1,7 @@
 """Persistent predicted-vs-measured cost ledger.
 
-Every kernelized execution with tracing enabled appends one JSONL record
-per kernel launch::
+Every kernelized execution under ``Query.explain(analyze=True)`` (its
+measured replay) appends one JSONL record per kernel launch::
 
     {"kernel": "group_probe", "dtype": "float64", "n": 262144,
      "bucket": 262144, "predicted_ns": 181000, "measured_ns": 240917,

@@ -6,6 +6,17 @@ no-op object so instrumented code pays one flag check per call site.
 Enable with ``repro.obs.enable()`` or ``WELD_TRACE=1`` in the
 environment.
 
+Each span knows its ``parent`` (the span open on the same thread when it
+opened) and the request it belongs to (``req``): ``request()`` opens a
+span with a fresh request id, and every span opened inside it on that
+thread inherits the id.  ``record()`` files an interval that started
+elsewhere, such as a request's wait in a queue.
+
+While tracing is on, every span opened by ``span()``/``request()`` also
+enters a ``jax.profiler.TraceAnnotation`` of the same name (with its
+``req`` as metadata), so a running ``jax.profiler`` trace holds the
+program's spans in its host plane, on the clock of the device's ops.
+
 Finished spans accumulate in a process-global list (pre-order: a span is
 registered when it *opens*, its duration is filled in when it closes) and
 can be exported as Chrome-trace/Perfetto JSON (``to_chrome``) or a
@@ -13,11 +24,14 @@ human-readable tree (``format_tree``).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
@@ -26,6 +40,8 @@ __all__ = [
     "enabled",
     "clear",
     "span",
+    "request",
+    "record",
     "event",
     "mark",
     "spans",
@@ -44,13 +60,19 @@ def _env_enabled(env: Optional[dict] = None) -> bool:
 
 
 class Span:
-    """One timed interval.  ``dur_ns`` is None while the span is open."""
+    """One timed interval.  ``dur_ns`` is None while the span is open.
+
+    ``sid`` numbers the span in the log; ``parent`` is the ``sid`` of the
+    span that was open on the same thread when this one opened (None at
+    the top); ``req`` is the id of the request the span belongs to (None
+    outside any ``request()``)."""
 
     __slots__ = ("name", "tags", "counters", "start_ns", "dur_ns",
-                 "depth", "tid")
+                 "depth", "tid", "sid", "parent", "req", "_ann")
 
     def __init__(self, name: str, tags: Optional[Dict[str, Any]] = None,
-                 depth: int = 0, tid: int = 0):
+                 depth: int = 0, tid: int = 0, parent: Optional[int] = None,
+                 req: Optional[int] = None):
         self.name = name
         self.tags: Dict[str, Any] = dict(tags) if tags else {}
         self.counters: Dict[str, float] = {}
@@ -58,6 +80,10 @@ class Span:
         self.dur_ns: Optional[int] = None
         self.depth = depth
         self.tid = tid
+        self.sid = 0
+        self.parent = parent
+        self.req = req
+        self._ann = None
 
     def set(self, key: str, value: Any) -> "Span":
         self.tags[key] = value
@@ -103,6 +129,9 @@ class _NoopSpan:
     dur_ns = 0
     depth = 0
     tid = 0
+    sid = 0
+    parent = None
+    req = None
 
 
 NOOP = _NoopSpan()
@@ -111,6 +140,8 @@ _enabled = _env_enabled()
 _lock = threading.Lock()
 _spans: List[Span] = []
 _tls = threading.local()
+_sids = itertools.count(1)
+_requests = itertools.count(1)
 
 
 def enable() -> None:
@@ -141,6 +172,34 @@ def _stack() -> List[Span]:
     return st
 
 
+def _register(sp: Span) -> None:
+    with _lock:
+        sp.sid = next(_sids)
+        _spans.append(sp)
+
+
+def _child(name: str, tags: dict, req: Optional[int] = None) -> Span:
+    """A registered span under the one open on this thread, in whose
+    request it is unless given its own."""
+    st = _stack()
+    top = st[-1] if st else None
+    if req is None and top is not None:
+        req = top.req
+    sp = Span(name, tags, depth=len(st), tid=threading.get_ident(),
+              parent=None if top is None else top.sid, req=req)
+    _register(sp)
+    return sp
+
+
+def _open(name: str, tags: dict, req: Optional[int]) -> Span:
+    sp = _child(name, tags, req)
+    _stack().append(sp)
+    sp._ann = (TraceAnnotation(name) if sp.req is None
+               else TraceAnnotation(name, req=sp.req))
+    sp._ann.__enter__()
+    return sp
+
+
 def span(name: str, **tags):
     """Open a span.  Use as a context manager::
 
@@ -152,16 +211,36 @@ def span(name: str, **tags):
     """
     if not _enabled:
         return NOOP
-    st = _stack()
-    sp = Span(name, tags, depth=len(st), tid=threading.get_ident())
-    st.append(sp)
-    with _lock:
-        _spans.append(sp)
+    return _open(name, tags, None)
+
+
+def request(name: str, **tags):
+    """Open a span that starts a new request: it gets a fresh ``req``,
+    which every span opened inside it on this thread inherits."""
+    if not _enabled:
+        return NOOP
+    return _open(name, tags, next(_requests))
+
+
+def record(name: str, start_ns: int, end_ns: int,
+           req: Optional[int] = None, **tags):
+    """File a finished interval on the ``perf_counter_ns`` clock, such as
+    one that began on another thread.  It has no parent and, having no
+    live extent, no profiler annotation."""
+    if not _enabled:
+        return NOOP
+    sp = Span(name, tags, tid=threading.get_ident(), req=req)
+    sp.start_ns = start_ns
+    sp.dur_ns = end_ns - start_ns
+    _register(sp)
     return sp
 
 
 def _close(sp: Span) -> None:
     sp.dur_ns = time.perf_counter_ns() - sp.start_ns
+    if sp._ann is not None:
+        sp._ann.__exit__(None, None, None)
+        sp._ann = None
     st = _stack()
     # tolerate out-of-order exits (exceptions unwind the whole stack)
     while st and st[-1] is not sp:
@@ -174,11 +253,8 @@ def event(name: str, **tags):
     """Record an instantaneous (zero-duration) span."""
     if not _enabled:
         return NOOP
-    sp = span(name, **tags)
+    sp = _child(name, tags)
     sp.dur_ns = 0
-    st = _stack()
-    if st and st[-1] is sp:
-        st.pop()
     return sp
 
 
@@ -201,7 +277,11 @@ def spans_since(pos: int) -> List[Span]:
 # ---------------------------------------------------------------- exports
 
 def _args_of(sp: Span) -> Dict[str, Any]:
-    args = {}
+    args: Dict[str, Any] = {"id": sp.sid}
+    if sp.parent is not None:
+        args["parent"] = sp.parent
+    if sp.req is not None:
+        args["req"] = sp.req
     for k, v in sp.tags.items():
         try:
             json.dumps(v)

@@ -27,6 +27,7 @@ calls, under the recovery ladder) drives the same stages end-to-end.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import re
 import threading
@@ -75,6 +76,9 @@ DEFAULT_CACHE_MAX = 256
 #: process-global state; executions of already-compiled programs run
 #: WITHOUT this lock, so concurrent serving only serializes on compiles.
 _compile_lock = threading.RLock()
+
+#: per-thread scope of :func:`measured_replays`
+_replay_scope = threading.local()
 
 
 def cache_max() -> int:
@@ -318,10 +322,10 @@ def _lower(prog, optimize, memory_limit, passes, mode,
     low = LoweredProgram(prog=prog, opt=optimize, memory_limit=memory_limit,
                          passes=passes, mode=mode, kernel_impl=kernel_impl)
     low.input_names = sorted(prog.inputs)
-    with obs.span("encode", inputs=len(low.input_names)):
+    with obs.span("encode", inputs=len(low.input_names)) as sp:
         for name in low.input_names:
             ty, enc, data = prog.inputs[name]
-            arr = jnp.asarray(enc.encode(data))
+            arr = _to_device(enc.encode(data), sp)
             low.arrays.append(arr)
             low.shapes[name] = tuple(arr.shape)
             low.types[name] = ty
@@ -333,6 +337,15 @@ def _lower(prog, optimize, memory_limit, passes, mode,
         low.kreg = _kreg_fingerprint()
     low.key = low.cache_key()
     return low
+
+
+def _to_device(host, encode_span):
+    """``jnp.asarray`` of one input, which queues its upload and returns;
+    the bytes of a host input count on the ``encode`` span."""
+    arr = jnp.asarray(host)
+    if obs.enabled() and not isinstance(host, jax.Array):
+        encode_span.count("bytes", arr.nbytes)
+    return arr
 
 
 @dataclass
@@ -562,36 +575,23 @@ class CompiledProgram:
         if arrays is None:
             arrays = low.arrays
         else:
-            arrays = [jnp.asarray(a) for a in arrays]
+            with obs.span("encode", inputs=len(arrays)) as sp:
+                arrays = [_to_device(a, sp) for a in arrays]
             sig = ",".join(f"{a.dtype}:{a.shape}" for a in arrays)
             if sig != low.sig:
                 raise ValueError(
                     f"CompiledProgram.run: bound inputs {sig} do not "
                     f"match the compiled signature {low.sig}; re-lower "
                     "and compile for new shapes/dtypes")
-        stats = self._cached_stats
         with obs.span("weld.run", from_cache=self.from_cache):
-            with obs.span("execute"):
-                out = self._jitted(*arrays)
-                out = jax.block_until_ready(out)
-            if (obs.enabled() and stats.get("kernelize.matched")
-                    and stats.get("plan.ir") is not None
-                    and stats.get("plan.inputs") is not None):
-                pnames, ptypes, pshapes = stats["plan.inputs"]
-                _measured_replay(stats["plan.ir"], pnames, ptypes, pshapes,
-                                 low.memory_limit, low.kernel_impl, arrays)
-            with obs.span("decode"):
-                try:
-                    faults.maybe_raise("decode")
-                    if faults.poisoned("decode"):
-                        raise CapacityError(
-                            "fault injected at decode: result poisoned")
-                    return decode_value(out, low.prog.out_ty)
-                except CapacityError:
-                    from . import recovery
+            try:
+                return _execute(low, self._jitted, self._cached_stats,
+                                arrays)
+            except CapacityError:
+                from . import recovery
 
-                    if not recover or not recovery.enabled():
-                        raise
+                if not recover or not recovery.enabled():
+                    raise
         # capacity poison under recovery: rebuild a Program bound to
         # THESE arrays and climb the full ladder (regrow → fallback)
         prog2 = Program(
@@ -682,21 +682,58 @@ def _compile_and_run(prog, optimize, memory_limit, passes, mode,
     jitted, stats, from_cache = _compile_handle(low)
     compile_ms = 0.0 if from_cache else stats.get("compile_ms", 0.0)
     root.set("from_cache", from_cache)
+    value = _execute(low, jitted, stats, low.arrays)
+    return value, compile_ms, from_cache, _export_stats(stats, from_cache)
+
+
+def _execute(low: LoweredProgram, jitted, stats: dict, arrays):
+    """Launch, wait for the result, decode: the one execution path of
+    ``CompiledProgram.run`` and of ``compile_and_run``.
+
+    The launch is queued at once, behind the inputs' upload.  While
+    tracing, ``upload`` then waits for the inputs to land, so that
+    ``execute`` times the device's queue and run alone; untraced,
+    nothing waits on the inputs.  Inside :func:`measured_replays` a
+    kernelized plan also runs its measured replay."""
+    with obs.span("upload"):
+        out = jitted(*arrays)
+        if obs.enabled():
+            jax.block_until_ready(arrays)
     with obs.span("execute"):
-        out = jitted(*low.arrays)
         out = jax.block_until_ready(out)
-    if (obs.enabled() and stats.get("kernelize.matched")
+    if (getattr(_replay_scope, "on", False)
+            and stats.get("kernelize.matched")
             and stats.get("plan.ir") is not None
             and stats.get("plan.inputs") is not None):
         pnames, ptypes, pshapes = stats["plan.inputs"]
         _measured_replay(stats["plan.ir"], pnames, ptypes, pshapes,
-                         memory_limit, kernel_impl, low.arrays)
+                         low.memory_limit, low.kernel_impl, arrays)
     with obs.span("decode"):
         faults.maybe_raise("decode")
         if faults.poisoned("decode"):
             raise CapacityError("fault injected at decode: result poisoned")
-        value = decode_value(out, prog.out_ty)
-    return value, compile_ms, from_cache, _export_stats(stats, from_cache)
+        # every device buffer of the result comes to the host here, all
+        # copies issued at once; decode_value then only slices host arrays
+        with obs.span("fetch") as sp:
+            if obs.enabled():
+                sp.count("bytes", sum(
+                    x.nbytes for x in jax.tree_util.tree_leaves(out)
+                    if isinstance(x, jax.Array)))
+            host = jax.device_get(out)
+        return decode_value(host, low.prog.out_ty)
+
+
+@contextlib.contextmanager
+def measured_replays():
+    """On this thread, for the block: every kernelized execution also
+    runs :func:`_measured_replay`.  ``Query.explain(analyze=True)`` is
+    the scope's one user; served and evaluated queries never replay."""
+    prev = getattr(_replay_scope, "on", False)
+    _replay_scope.on = True
+    try:
+        yield
+    finally:
+        _replay_scope.on = prev
 
 
 def _measured_replay(expr, input_names, types, shapes, memory_limit,
@@ -704,9 +741,9 @@ def _measured_replay(expr, input_names, types, shapes, memory_limit,
     """Re-run the planned program eagerly (unjitted) with per-kernel
     timing enabled, so each ``KernelCall`` gets its own measured span and
     a cost-ledger record.  The fused jitted executable gives no per-call
-    boundaries, so when tracing is on we pay one extra eager pass to get
-    honest per-kernel wall times (adapter overhead included — the same
-    thing the roofline model prices).  Best-effort: a replay failure is
+    boundaries, so EXPLAIN ANALYZE pays one extra eager pass to get
+    per-kernel wall times (adapter overhead included — the same thing
+    the roofline model prices).  Best-effort: a replay failure is
     recorded on the span, never raised.  Serialized under the compile
     lock: the eager pass runs through the same global emitter state a
     concurrent compile would be mutating."""

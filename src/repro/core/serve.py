@@ -38,6 +38,7 @@ inside the compile pipeline; it still precedes every expensive step.
 from __future__ import annotations
 
 import threading
+import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, List, Optional, Tuple
 
@@ -90,7 +91,8 @@ class QueryServer:
             raise RuntimeError("QueryServer is closed")
         with self._lock:
             self._counters["serve.requests"] += 1
-        return self._pool.submit(self._serve_one, query)
+        queued_ns = time.perf_counter_ns() if obs.enabled() else 0
+        return self._pool.submit(self._serve_one, query, queued_ns)
 
     def run(self, query):
         """Synchronous :meth:`submit`."""
@@ -135,28 +137,41 @@ class QueryServer:
 
     # -- the per-request pipeline -------------------------------------------
 
-    def _serve_one(self, query):
-        prog, finalize, op, limit, kz, ki = self._normalize(query)
-        with obs.span("serve.request", op=op):
+    def _serve_one(self, query, queued_ns: int = 0):
+        """One request on a worker thread.  Its ``serve.request`` span
+        covers everything the worker does for it, program stitching
+        included; ``serve.queue`` is the wait since :meth:`submit`."""
+        with obs.request("serve.request") as sp:
             try:
-                from . import runtime
+                return self._serve(query, sp)
+            finally:
+                if queued_ns:
+                    obs.record("serve.queue", queued_ns, sp.start_ns,
+                               req=sp.req)
 
-                handle = runtime.compile_program(
-                    prog, memory_limit=limit, kernelize=kz, kernel_impl=ki)
-                value = handle.run()
+    def _serve(self, query, sp):
+        prog, finalize, op, limit, kz, ki = self._normalize(query)
+        sp.set("op", op)
+        try:
+            from . import runtime
+
+            handle = runtime.compile_program(
+                prog, memory_limit=limit, kernelize=kz, kernel_impl=ki)
+            value = handle.run()
+            with obs.span("frames.finalize"):
                 result = finalize(value)
-            except ResourceError as e:
-                obs.event("serve.shed", op=op, reason=str(e))
-                with self._lock:
-                    self._counters["serve.shed"] += 1
-                raise
-            except BaseException:
-                with self._lock:
-                    self._counters["serve.errors"] += 1
-                raise
+        except ResourceError as e:
+            obs.event("serve.shed", op=op, reason=str(e))
             with self._lock:
-                self._counters["serve.completed"] += 1
-            return result
+                self._counters["serve.shed"] += 1
+            raise
+        except BaseException:
+            with self._lock:
+                self._counters["serve.errors"] += 1
+            raise
+        with self._lock:
+            self._counters["serve.completed"] += 1
+        return result
 
     def _normalize(self, query) -> Tuple[Program, Callable, str,
                                          Optional[int], object,

@@ -9,6 +9,7 @@ benchmarks and the UDF workload.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -100,8 +101,9 @@ class Query:
         kernel with its block parameters and roofline estimate, and the
         planner's route/reject decisions.  With ``analyze=True`` the
         query also runs with tracing enabled, adding per-span measured
-        times and predicted-vs-measured ratios per kernel launch (the
-        operator's result is still computed and available as
+        times, and a kernelized plan runs once more eagerly to time each
+        kernel launch, adding predicted-vs-measured ratios per launch
+        (the operator's result is still computed and available as
         ``rep.result``)."""
         return _Explain(self, analyze)
 
@@ -899,7 +901,7 @@ class _Explain:
         return self._capture("join", args, kwargs)
 
     def _capture(self, op: str, args, kwargs) -> "PlanReport":
-        from ..core import obs
+        from ..core import obs, runtime
 
         if self._q.table.eager:
             raise ValueError(
@@ -913,8 +915,11 @@ class _Explain:
         if self._analyze:
             obs.enable()
         pos = obs.mark()
+        replays = (runtime.measured_replays() if self._analyze
+                   else contextlib.nullcontext())
         try:
-            result = getattr(Query, op)(self._q, *args, **kwargs)
+            with replays:
+                result = getattr(Query, op)(self._q, *args, **kwargs)
         finally:
             if self._analyze and not was_on:
                 obs.disable()

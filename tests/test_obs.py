@@ -291,3 +291,182 @@ def test_repro_obs_alias():
 
     assert topobs.enable is obs.enable
     assert topobs.ledger is ledger
+
+
+# ---------------------------------------------------------------------------
+# request ids, parents, recorded intervals, the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_request_ids_and_parents_propagate():
+    obs.enable()
+    with obs.span("outside") as outside:
+        pass
+    with obs.request("req.a") as a:
+        with obs.span("child") as child:
+            with obs.span("grandchild") as grand:
+                obs.event("tick")
+    with obs.request("req.b") as b:
+        pass
+    assert outside.req is None and outside.parent is None
+    assert a.req is not None and b.req is not None and a.req != b.req
+    assert a.parent is None
+    assert child.req == grand.req == a.req
+    assert child.parent == a.sid and grand.parent == child.sid
+    tick = [s for s in obs.spans() if s.name == "tick"][0]
+    assert tick.parent == grand.sid and tick.req == a.req
+    assert len({s.sid for s in obs.spans()}) == len(obs.spans())
+
+
+def test_record_files_a_finished_interval():
+    import time
+
+    obs.enable()
+    t0 = time.perf_counter_ns()
+    with obs.request("req") as r:
+        rec = obs.record("waited", t0, r.start_ns, req=r.req, why="test")
+    assert rec.dur_ns == r.start_ns - t0 >= 0
+    assert rec.req == r.req and rec.parent is None
+    assert rec.tags == {"why": "test"}
+    assert rec in obs.spans()
+    obs.disable()
+    assert obs.record("off", 0, 1) is obs.NOOP
+
+
+def test_chrome_export_carries_req_and_parent():
+    obs.enable()
+    with obs.request("root") as root:
+        with obs.span("leaf"):
+            pass
+    evs = obs.to_chrome()["traceEvents"]
+    by = {e["name"]: e["args"] for e in evs}
+    assert by["root"]["req"] == root.req and "parent" not in by["root"]
+    assert by["leaf"]["req"] == root.req
+    assert by["leaf"]["parent"] == by["root"]["id"] == root.sid
+
+
+def test_disabled_tracing_annotates_nothing_and_blocks_on_no_input(
+        monkeypatch):
+    """Tracing off: no profiler annotation is made and the runtime never
+    waits on the inputs' upload; tracing on, the ``upload`` span does."""
+    import jax
+
+    from repro.core import runtime
+    from repro.core.lazy import build_program
+    from repro.core.obs import tracer
+    from repro.frames import weldnp
+
+    made, blocked = [], []
+    real_block = jax.block_until_ready
+
+    class Counting(tracer.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    def block(x):
+        blocked.append(x)
+        return real_block(x)
+
+    monkeypatch.setattr(tracer, "TraceAnnotation", Counting)
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    x = weldnp.array(np.arange(64, dtype=np.float64))
+    handle = runtime.compile_program(build_program((x + 1.0).obj))
+    inputs = handle._low.arrays
+    blocked.clear()
+    handle.run()
+    with obs.request("r"):
+        obs.span("s")
+        obs.event("e")
+    assert made == []
+    assert not any(b is inputs for b in blocked)
+    obs.enable()
+    handle.run()
+    assert any(b is inputs for b in blocked)
+    assert ("upload",) in made and ("execute",) in made
+
+
+def test_encode_and_fetch_count_their_bytes_exactly():
+    import jax
+
+    from repro.core import runtime
+    from repro.frames.weldrel import Query, Table
+
+    rng = np.random.default_rng(3)
+    t = Table({"k": rng.integers(0, 50, 3000), "x": rng.normal(size=3000)})
+    b = Table({"k": np.arange(50), "w": rng.normal(size=50)})
+    prog = Query(t).stage().join(b, on="k", validate="m:1").program()
+    want_in = sum(np.asarray(d).nbytes for _, _, d in prog.inputs.values())
+    obs.enable()
+    pos = obs.mark()
+    handle = runtime.compile_program(prog)
+    handle.run()
+    spans = obs.spans_since(pos)
+    out = handle._jitted(*handle._low.arrays)
+    want_out = sum(x.nbytes for x in jax.tree_util.tree_leaves(out))
+    encode = [s for s in spans if s.name == "encode"]
+    fetch = [s for s in spans if s.name == "fetch"]
+    assert len(encode) == 1 and len(fetch) == 1
+    assert encode[0].counters["bytes"] == want_in
+    assert fetch[0].counters["bytes"] == want_out
+    decode = [s for s in spans if s.name == "decode"][0]
+    assert fetch[0].parent == decode.sid
+
+
+def test_spans_land_in_the_profilers_host_plane(tmp_path):
+    """With weldtrace on under ``jax.profiler``, every span a served
+    request opens is an event of the trace's host plane, of the same
+    length and nesting, and ``bench.trace.label`` names a gap by one."""
+    import jax
+
+    from repro.core.serve import QueryServer
+    from repro.frames.weldrel import Query, Table
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from bench import trace
+
+    rng = np.random.default_rng(5)
+    t = Table({"k": rng.integers(0, 100, 20000), "x": rng.normal(size=20000)})
+    b = Table({"k": np.arange(100), "w": rng.normal(size=100)})
+    with QueryServer(workers=1) as srv:
+        srv.run(Query(t).stage().join(b, on="k", validate="m:1"))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        obs.enable()
+        pos = obs.mark()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            srv.run(Query(t).stage().join(b, on="k", validate="m:1"))
+        finally:
+            jax.profiler.stop_trace()
+            obs.disable()
+    # events are instants, and the queue's record began on another thread
+    spans = [s for s in obs.spans_since(pos)
+             if s.dur_ns and s.name != "serve.queue"]
+    host = trace.load(str(tmp_path))["host"]
+    by_name: dict = {}
+    for ev in sorted(host, key=lambda e: e[1]):
+        by_name.setdefault(ev[0], []).append(ev)
+    seen: dict = {}
+    where = {}
+    for sp in spans:
+        evs = by_name.get(sp.name, [])
+        k = seen.get(sp.name, 0)
+        assert k < len(evs), f"{sp.name} missing from the host plane"
+        seen[sp.name] = k + 1
+        _, start, dur = evs[k]
+        assert abs(dur - sp.dur_ns) <= max(0.05 * sp.dur_ns, 100_000), \
+            (sp.name, dur, sp.dur_ns)
+        where[sp.sid] = (start, start + dur)
+    for sp in spans:
+        if sp.parent in where:
+            lo, hi = where[sp.parent]
+            s, e = where[sp.sid]
+            assert lo <= s and e <= hi, sp.name
+    names = {sp.name for sp in spans}
+    assert {"serve.request", "encode", "weld.compile", "weld.run", "upload",
+            "execute", "decode", "fetch", "frames.finalize"} <= names
+    fin = [sp for sp in spans if sp.name == "frames.finalize"][0]
+    assert trace.label(where[fin.sid], host) == "frames.finalize"

@@ -12,7 +12,7 @@ import warnings
 import numpy as np
 import pytest
 
-from repro.core import faults, obs, recovery, runtime
+from repro.core import faults, recovery, runtime
 from repro.core.errors import (
     CapacityError, InjectedFault, KernelCompileError, ResourceError,
     WeldError,
@@ -383,24 +383,17 @@ def test_kernel_generic_overflow_parity():
 
 
 def test_measured_replay_failure_is_tagged_not_raised(tmp_path):
-    """An injected failure inside the traced eager replay must land on
-    the measure.replay span as error=..., never propagate, and write no
-    bogus ledger record."""
+    """An injected failure inside EXPLAIN ANALYZE's eager replay must
+    land on the measure.replay span as error=..., never propagate, and
+    write no bogus ledger record."""
     from repro.core.obs import ledger
 
     L, R = _join_tables()
     faults.inject("measure.replay", "raise", times=1)
-    was_on = obs.enabled()
-    obs.enable()
-    pos = obs.mark()
-    try:
-        got = Query(L).join(R, on="k", kernelize="always")
-    finally:
-        if not was_on:
-            obs.disable()
+    rep = Query(L).explain(analyze=True).join(R, on="k", kernelize="always")
+    got = rep.result
     assert len(_rowset(got)) == 7  # the fault never reached the caller
-    spans = obs.spans_since(pos)
-    replay = [sp for sp in spans if sp.name == "measure.replay"]
+    replay = [sp for sp in rep.spans if sp.name == "measure.replay"]
     assert replay and "InjectedFault" in replay[0].tags["error"]
     assert ledger.read(str(tmp_path / "ledger.jsonl")) == []
 

@@ -203,6 +203,80 @@ def test_concurrent_mixed_queries_byte_identical_single_flight():
                     np.asarray(want[key], dtype=float))
 
 
+@pytest.fixture
+def traced():
+    obs.disable()
+    obs.clear()
+    obs.enable()
+    yield
+    obs.disable()
+    obs.clear()
+
+
+def test_request_spans_share_req_and_nest_under_serve_request(traced):
+    """Every span a served request opens on its worker carries the
+    request's id and a parent inside it; ``serve.queue`` spans the
+    wait from ``submit`` on the caller's thread to the worker."""
+    probe, build = _tables(n=5000, k=20)
+    with QueryServer(workers=2) as srv:
+        futs = [srv.submit(Query(Table(dict(probe))).stage().join(
+            Table(dict(build)), on="k", validate="m:1")) for _ in range(4)]
+        for f in futs:
+            f.result()
+    spans = obs.spans()
+    reqs = [sp for sp in spans if sp.name == "serve.request"]
+    assert len(reqs) == 4 and len({sp.req for sp in reqs}) == 4
+    assert all(sp.tags["op"] == "join" for sp in reqs)
+    by_sid = {sp.sid: sp for sp in spans}
+    for req in reqs:
+        mine = [sp for sp in spans if sp.req == req.req]
+        names = {sp.name for sp in mine}
+        assert {"serve.queue", "encode", "weld.compile", "weld.run",
+                "upload", "execute", "decode", "fetch",
+                "frames.finalize"} <= names, names
+        queue = [sp for sp in mine if sp.name == "serve.queue"]
+        assert len(queue) == 1 and queue[0].parent is None
+        assert queue[0].start_ns + queue[0].dur_ns == req.start_ns
+        for sp in mine:
+            if sp is req or sp is queue[0]:
+                continue
+            up = sp
+            while up.parent is not None and up is not req:
+                up = by_sid[up.parent]
+                assert up.req == req.req and up.tid == req.tid
+            assert up is req, sp.name
+    run = [sp for sp in spans if sp.name == "weld.run"][0]
+    chain = {sp.name: sp for sp in spans
+             if sp.req == run.req and sp.parent is not None}
+    assert chain["fetch"].parent == chain["decode"].sid
+    assert chain["decode"].parent == run.sid
+    assert chain["frames.finalize"].parent == by_sid[run.parent].sid
+
+
+def test_traced_serving_never_replays_nor_writes_the_ledger(traced):
+    """Tracing on (as ``WELD_TRACE=1`` sets it) re-executes nothing
+    outside ``explain(analyze=True)``: a served kernelized query has no
+    eager replay and leaves the cost ledger empty."""
+    probe, build = _tables(n=4000, k=32)
+    dup = {"k": np.concatenate([build["k"], build["k"]]),
+           "w": np.concatenate([build["w"], build["w"] + 1.0])}
+    with QueryServer(workers=1, kernelize="always") as srv:
+        for _ in range(2):
+            srv.run(Query(Table(dict(probe))).stage().join(
+                Table(dict(dup)), on="k"))
+    names = {sp.name for sp in obs.spans()}
+    assert any(sp.name == "kernelplan" and sp.tags.get("matched")
+               for sp in obs.spans())  # the served plan is kernelized
+    assert "execute" in names
+    assert "measure.replay" not in names
+    assert not any(n.startswith("kernel.") for n in names)
+    assert ledger.read(os.environ["WELD_COST_LEDGER"]) == []
+    rep = Query(Table(dict(probe))).explain(analyze=True).join(
+        Table(dict(dup)), on="k", kernelize="always")
+    assert rep.kernel_spans()
+    assert ledger.read(os.environ["WELD_COST_LEDGER"])
+
+
 def test_single_flight_one_compile_under_thundering_herd():
     probe, build = _tables()
     reqs = [Query(Table(dict(probe))).stage().join(

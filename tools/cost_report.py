@@ -82,7 +82,8 @@ def main() -> int:
         if rows:
             print(ledger.format_report(rows))
         else:
-            print("# no records — run a kernelized query with WELD_TRACE=1")
+            print("# no records — run Query.explain(analyze=True) on a "
+                  "kernelized query")
     return 0
 
 

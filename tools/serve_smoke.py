@@ -13,8 +13,8 @@ actually hold under concurrency:
   same-shape tables spends zero additional compiles;
 * admission: a provably over-budget query sheds with a typed
   ``ResourceError`` and never enters the compile cache;
-* calibration: ledger medians seeded from an authentic traced run
-  overlay the roofline estimates — the recompiled plan's ``explain()``
+* calibration: ledger medians seeded from an authentic EXPLAIN ANALYZE
+  run overlay the roofline estimates — the recompiled plan's ``explain()``
   shows ``source=measured`` provenance WITHOUT flipping any routing
   decision (the seeded medians equal the roofline predictions).
 
@@ -34,7 +34,6 @@ _td = tempfile.mkdtemp(prefix="weld-serve-smoke-")
 os.environ["WELD_AUTOTUNE_CACHE"] = os.path.join(_td, "autotune.json")
 os.environ["WELD_COST_LEDGER"] = os.path.join(_td, "cost_ledger.jsonl")
 os.environ["WELD_COMPILE_CACHE_MAX"] = "8"
-os.environ.setdefault("WELD_TRACE", "1")  # measured replay -> ledger
 
 import numpy as np  # noqa: E402
 
@@ -149,18 +148,16 @@ def main() -> int:
     print("rebind: same-shape run(**tables) spent 0 recompiles")
 
     # -- calibration: measured medians overlay the roofline --------------
-    # a fresh ledger: the traced runs above recorded AUTHENTIC (slow
-    # CPU) medians for every routed kernel, which would calibrate — and
-    # legitimately flip — the baseline compile we diff against below
+    # a fresh ledger for the calibration below
     os.environ["WELD_COST_LEDGER"] = os.path.join(_td, "ledger_cal.jsonl")
     calibrate.invalidate()
     runtime.clear_cache()
-    # authentic records: a traced always-routed m:n join writes one
-    # ledger row per kernel launch (predicted AND measured)
-    Query(Table(dict(pa))).join(Table(dict(dup)), on="k",
-                                kernelize="always")
+    # authentic records: EXPLAIN ANALYZE of an always-routed m:n join
+    # writes one ledger row per kernel launch (predicted AND measured)
+    Query(Table(dict(pa))).explain(analyze=True).join(
+        Table(dict(dup)), on="k", kernelize="always")
     recs = ledger.read()
-    assert recs, "traced always-run must seed the cost ledger"
+    assert recs, "EXPLAIN ANALYZE must seed the cost ledger"
     # pre-calibration baseline under auto: routing decisions + provenance
     base = makers[2]().compile()
     base_costs = {c["kernel"]: bool(c["routed"])

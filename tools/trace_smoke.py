@@ -7,7 +7,8 @@ on, then asserts the whole observability surface end to end:
 * the Chrome-trace export is valid JSON with the expected span names
   and monotonic nested spans (children inside their parents);
 * ``Query.explain(analyze=True)`` shows ``group_build``/``group_probe``
-  launches with BOTH predicted and measured times;
+  launches with BOTH predicted and measured times, from the per-kernel
+  spans of its eager replay (the only place the replay runs);
 * the cost ledger received records and ``tools/cost_report.py``
   summarizes it without error.
 
@@ -56,18 +57,23 @@ def main() -> int:
         r = launches.get(kern)
         assert r, f"missing measured {kern} launch: {launches}"
         assert r["predicted_ns"] and r["measured_ns"], (kern, r)
+        assert f"kernel.{kern}" in {sp.name for sp in rep.spans}, kern
     text = rep.render()
     for needle in ("EXPLAIN ANALYZE", "kernel[group_build]",
                    "kernel[group_probe]", "predicted vs measured"):
         assert needle in text, f"explain output missing {needle!r}"
     print("explain(analyze=True): group_build + group_probe measured OK")
 
-    # -- group-by query, plain tracing ----------------------------------
+    # -- group-by query, plain tracing: no replay outside explain --------
+    pos = obs.mark()
     st: dict = {}
     grouped = weldrel.Query(left).group_agg(
         [left.col("key")], {"s": (left.col("price"), "+")},
         capacity=2 * k, kernelize="auto", collect_stats=st)
     assert grouped, "group-by returned nothing"
+    replayed = {sp.name for sp in obs.spans_since(pos)
+                if sp.name.startswith(("measure.replay", "kernel."))}
+    assert not replayed, f"traced query replayed: {sorted(replayed)}"
 
     # -- trace export: valid JSON, expected names, monotonic nesting ----
     trace_path = os.path.join(_td, "trace.json")
@@ -77,24 +83,20 @@ def main() -> int:
     events = trace["traceEvents"]
     names = {e["name"] for e in events}
     for want in ("weld.evaluate", "optimize", "pass.fusion", "kernelplan",
-                 "jit_compile", "execute", "decode", "cache.lookup",
-                 "kernel.group_build", "kernel.group_probe"):
+                 "jit_compile", "upload", "execute", "decode", "fetch",
+                 "cache.lookup"):
         assert want in names, f"trace missing span {want!r}: {sorted(names)}"
     assert all(e["ph"] == "X" and e["dur"] >= 0 for e in events)
-    # nesting: every span must sit inside the evaluate span that opened
-    # before it (spans are recorded in pre-order per thread)
+    # nesting: every span sits inside its parent
     spans = obs.spans()
-    stack: list = []
+    by_sid = {sp.sid: sp for sp in spans}
     for sp in spans:
-        while stack and sp.depth <= stack[-1].depth:
-            stack.pop()
-        if stack:
-            parent = stack[-1]
+        parent = by_sid.get(sp.parent)
+        if parent is not None:
             end = parent.start_ns + (parent.dur_ns or 0)
             assert sp.start_ns >= parent.start_ns, (sp.name, parent.name)
             assert sp.start_ns + (sp.dur_ns or 0) <= end + 1_000_000, \
                 (sp.name, parent.name)
-        stack.append(sp)
     print(f"chrome trace OK: {len(events)} events, nesting monotonic")
 
     # -- ledger + report CLI --------------------------------------------
