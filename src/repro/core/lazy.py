@@ -9,6 +9,7 @@ application's in-memory data (zero-copy for numpy/jax arrays).
 from __future__ import annotations
 
 import itertools
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
@@ -90,12 +91,27 @@ class WeldResult:
         self.value = None
 
 
+class DeviceSlot:
+    """The encoded device copy of one data object.  The first program
+    that binds the object fills it under ``lock`` (``runtime._lower``), so
+    concurrent first binds upload once; every later bind takes ``value``."""
+
+    __slots__ = ("lock", "value")
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.value = None
+
+
 class WeldObject:
     """A lazily-evaluated computation or a wrapped external value.
 
     Data objects:  `expr` is an Ident referring to themselves; `data` holds
     the native value.  Computation objects: `expr` is Weld IR whose free
-    variables refer to entries of `deps`.
+    variables refer to entries of `deps`.  `resident` is the
+    :class:`DeviceSlot` of a data object whose owner keeps it on the
+    device (a ``weldrel.Table`` column); None re-uploads it on every
+    evaluation.
     """
 
     def __init__(
@@ -113,6 +129,7 @@ class WeldObject:
         self.data = data
         self._ty = ty
         self._freed = False
+        self.resident: Optional[DeviceSlot] = None
 
     # -- paper API ---------------------------------------------------------
 
@@ -137,6 +154,7 @@ class WeldObject:
         self.expr = None
         self.deps = {}
         self.data = None
+        self.resident = None
 
     def __repr__(self) -> str:
         kind = "data" if self.is_data else "lazy"
@@ -203,6 +221,8 @@ class Program:
     #: name -> (weld type, encoder, native value)
     inputs: Dict[str, Tuple[wt.WeldType, Encoder, object]]
     out_ty: wt.WeldType = None  # type: ignore
+    #: name -> the device slot of an input kept on the device
+    resident: Dict[str, DeviceSlot] = field(default_factory=dict)
 
     def evaluate(
         self,
@@ -253,12 +273,15 @@ def build_program(root: WeldObject) -> Program:
     topo(root)
 
     inputs: Dict[str, Tuple[wt.WeldType, Encoder, object]] = {}
+    resident: Dict[str, DeviceSlot] = {}
     bindings: List[Tuple[str, ir.Expr]] = []
     for o in order:
         if o._freed:
             raise RuntimeError(f"{o.obj_id} was freed before evaluation")
         if o.data is not None:
             inputs[o.obj_id] = (o.weld_type(), o.encoder, o.data)
+            if o.resident is not None:
+                resident[o.obj_id] = o.resident
         else:
             bindings.append((o.obj_id, o.expr))
 
@@ -272,7 +295,8 @@ def build_program(root: WeldObject) -> Program:
 
     env = {k: v[0] for k, v in inputs.items()}
     out_ty = ir.typeof(body, env)
-    return Program(expr=body, inputs=inputs, out_ty=out_ty)
+    return Program(expr=body, inputs=inputs, out_ty=out_ty,
+                   resident=resident)
 
 
 # ---------------------------------------------------------------------------

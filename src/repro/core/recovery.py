@@ -208,7 +208,8 @@ def run_with_recovery(runner, prog, *, optimize, memory_limit, passes,
 
                 check.verify_rewrite("recovery.regrow", prog.expr, grown)
                 cur_prog = type(prog)(expr=grown, inputs=prog.inputs,
-                                      out_ty=prog.out_ty)
+                                      out_ty=prog.out_ty,
+                                      resident=prog.resident)
                 detail = (f"capacity poison; regrowing {n_stamped} "
                           f"builder capacit{'y' if n_stamped == 1 else 'ies'}"
                           f" x{factor}")

@@ -16,7 +16,7 @@ recompile:
   passes, kernel planning, autotuning, weldbound admission;
 * ``OptimizedProgram.compile()`` / ``LoweredProgram.compile()`` /
   :func:`compile_program` → :class:`CompiledProgram` — the reusable AOT
-  handle with ``.stats`` and ``.run(arrays)``.
+  handle with ``.stats`` and ``.run(inputs)``.
 
 The compile cache is a bounded, locked, single-flight LRU
 (``$WELD_COMPILE_CACHE_MAX``, default 256): one thread compiles a given
@@ -322,10 +322,10 @@ def _lower(prog, optimize, memory_limit, passes, mode,
     low = LoweredProgram(prog=prog, opt=optimize, memory_limit=memory_limit,
                          passes=passes, mode=mode, kernel_impl=kernel_impl)
     low.input_names = sorted(prog.inputs)
-    with obs.span("encode", inputs=len(low.input_names)) as sp:
+    with _encode_span(len(low.input_names)) as sp:
         for name in low.input_names:
             ty, enc, data = prog.inputs[name]
-            arr = _to_device(enc.encode(data), sp)
+            arr = _bind(enc, data, prog.resident.get(name), sp)
             low.arrays.append(arr)
             low.shapes[name] = tuple(arr.shape)
             low.types[name] = ty
@@ -339,6 +339,16 @@ def _lower(prog, optimize, memory_limit, passes, mode,
     return low
 
 
+def _encode_span(n_inputs: int):
+    """The ``encode`` span of one binding of ``n_inputs`` inputs.  Its
+    counters start at 0: ``bytes`` counts the bytes uploaded, ``resident``
+    the inputs bound from a filled :class:`~repro.core.lazy.DeviceSlot`."""
+    sp = obs.span("encode", inputs=n_inputs)
+    sp.count("bytes", 0)
+    sp.count("resident", 0)
+    return sp
+
+
 def _to_device(host, encode_span):
     """``jnp.asarray`` of one input, which queues its upload and returns;
     the bytes of a host input count on the ``encode`` span."""
@@ -346,6 +356,20 @@ def _to_device(host, encode_span):
     if obs.enabled() and not isinstance(host, jax.Array):
         encode_span.count("bytes", arr.nbytes)
     return arr
+
+
+def _bind(enc, data, slot, encode_span):
+    """One input on the device.  Without a slot it is encoded and
+    uploaded at every bind.  A slot is filled by the first bind, one
+    thread at a time, and every later bind takes its buffer."""
+    if slot is None:
+        return _to_device(enc.encode(data), encode_span)
+    with slot.lock:
+        if slot.value is None:
+            slot.value = _to_device(enc.encode(data), encode_span)
+            return slot.value
+    encode_span.count("resident")
+    return slot.value
 
 
 @dataclass
@@ -563,20 +587,26 @@ class CompiledProgram:
         """dtype:shape signature the executable was compiled against."""
         return self._low.sig
 
-    def run(self, arrays=None, *, recover: bool = True):
-        """Execute against ``arrays`` (encoded, positional; None = the
-        inputs the handle was lowered with) and decode the result.
+    def run(self, inputs=None, *, recover: bool = True):
+        """Execute and decode the result: against the inputs the handle
+        was lowered with, or with ``inputs`` bound anew.  ``inputs`` maps
+        an input name to its native value and its device slot (None for
+        a value without one), each bound as :func:`lower` binds it; the
+        inputs it leaves out keep the handle's own.
 
         Same shapes+dtypes are the caller's contract (checked against
         the compiled signature).  On capacity poison — re-bound data
         overflowing the plan's baked builder capacities — the full
         recovery ladder re-runs the program with regrown capacities."""
         low = self._low
-        if arrays is None:
-            arrays = low.arrays
-        else:
-            with obs.span("encode", inputs=len(arrays)) as sp:
-                arrays = [_to_device(a, sp) for a in arrays]
+        arrays = low.arrays
+        if inputs is not None:
+            arrays = list(arrays)
+            pos = {name: i for i, name in enumerate(low.input_names)}
+            with _encode_span(len(inputs)) as sp:
+                for name, (data, slot) in inputs.items():
+                    arrays[pos[name]] = _bind(low.prog.inputs[name][1],
+                                              data, slot, sp)
             sig = ",".join(f"{a.dtype}:{a.shape}" for a in arrays)
             if sig != low.sig:
                 raise ValueError(

@@ -16,17 +16,30 @@ import numpy as np
 
 from ..core import faults, ir, macros as M, wtypes as wt
 from ..core.errors import CapacityError
-from ..core.lazy import Evaluate, NewWeldObject, WeldObject, build_program
+from ..core.lazy import (DeviceSlot, Evaluate, NewWeldObject, WeldObject,
+                         build_program)
 from . import weldnp
 
 
 class Table:
+    """A column table over a snapshot of its columns.
+
+    The table holds its host columns read-only: a writeable array is
+    copied once, here, and a read-only one (with read-only bases) is
+    taken as it is, so writing into the caller's array afterwards
+    changes no answer.  A lazy table's column is uploaded to the device
+    by the first program that binds it; every later program binds that
+    device copy, which is freed with the table."""
+
     def __init__(self, columns: Dict[str, np.ndarray], eager: bool = False):
         self.eager = eager
         self.cols = {
-            k: weldnp.array(np.asarray(v), eager=eager)
+            k: weldnp.array(_snapshot(v), eager=eager)
             for k, v in columns.items()
         }
+        if not eager:
+            for c in self.cols.values():
+                c.obj.resident = DeviceSlot()
 
     def col(self, name: str) -> weldnp.ndarray:
         return self.cols[name]
@@ -543,7 +556,7 @@ class Query:
             found = cnt > 0
             if how == "anti":
                 mask = mrows & ~found
-                return Table(
+                return _result_table(
                     {c: self.table.col(c)._eager[mask] for c in names_l},
                     eager=True,
                 )
@@ -568,7 +581,7 @@ class Query:
                     else:
                         v = np.full(rows.size, fill, rcol.dtype)
                     out[name] = v
-            return Table(out, eager=True)
+            return _result_table(out, eager=True)
 
         # -- lazy: one fused program (build + ONE fused probe) -----------------
         lcols = {c: _as_lazy(self.table.cols[c]) for c in names_l}
@@ -739,7 +752,7 @@ class Query:
             obj = NewWeldObject(deps, ir.Result(loop))
             return self._finish(
                 obj,
-                lambda v: Table(
+                lambda v: _result_table(
                     dict(zip(out_names, [np.asarray(a) for a in v])),
                     eager=False),
                 op="join", tables={"table": self.table, "right": other},
@@ -874,7 +887,7 @@ class Query:
         obj = NewWeldObject(deps, ir.Result(loop))
         return self._finish(
             obj,
-            lambda v: Table(
+            lambda v: _result_table(
                 dict(zip(out_names, [np.asarray(a) for a in v])),
                 eager=False),
             op="join", tables={"table": self.table, "right": other},
@@ -1213,8 +1226,6 @@ class CompiledQuery:
         self.staged = staged
         self.handle = handle
         self._binding = staged.binding()
-        self._pos = {name: i
-                     for i, name in enumerate(handle._low.input_names)}
 
     @property
     def stats(self) -> dict:
@@ -1231,31 +1242,53 @@ class CompiledQuery:
                           analyze=False, result=None)
 
     def run(self, **tables):
-        prog = self.staged.program()
-        arrays = None
-        if tables:
-            arrays = list(self.handle._low.arrays)
-            for alias, tbl in tables.items():
-                mapping = self._binding.get(alias)
-                if mapping is None:
+        if not tables:
+            return self.staged.finalize(self.handle.run())
+        inputs = {}
+        for alias, tbl in tables.items():
+            mapping = self._binding.get(alias)
+            if mapping is None:
+                raise KeyError(
+                    f"unknown table alias {alias!r}; this "
+                    f"{self.staged.op} binds {sorted(self._binding)}")
+            for cname, iname in mapping.items():
+                if cname not in tbl.cols:
                     raise KeyError(
-                        f"unknown table alias {alias!r}; this "
-                        f"{self.staged.op} binds {sorted(self._binding)}")
-                for cname, iname in mapping.items():
-                    if cname not in tbl.cols:
-                        raise KeyError(
-                            f"re-bound table {alias!r} is missing column "
-                            f"{cname!r} required by the compiled plan")
-                    enc = prog.inputs[iname][1]
-                    arrays[self._pos[iname]] = enc.encode(
-                        np.asarray(_host(tbl.cols[cname])))
-        value = self.handle.run(arrays)
-        return self.staged.finalize(value)
+                        f"re-bound table {alias!r} is missing column "
+                        f"{cname!r} required by the compiled plan")
+                col = tbl.cols[cname]
+                inputs[iname] = (_host(col), None if col.is_eager
+                                 else col.obj.resident)
+        return self.staged.finalize(self.handle.run(inputs))
 
 
 def _host(col: weldnp.ndarray) -> np.ndarray:
     """The numpy buffer behind a table column (eager or lazy)."""
     return col._eager if col.is_eager else np.asarray(col.obj.data)
+
+
+def _snapshot(v) -> np.ndarray:
+    """``v`` as an array nobody can write: itself where it and every
+    array it views are read-only, else a read-only copy."""
+    a = np.asarray(v)
+    b = a
+    while isinstance(b, np.ndarray):
+        if b.flags.writeable:
+            a = a.copy()
+            a.flags.writeable = False
+            return a
+        b = b.base
+    return a
+
+
+def _result_table(cols: Dict[str, np.ndarray], eager: bool) -> Table:
+    """A Table over a join's own fresh answer arrays, marked read-only
+    (with the arrays they view) so that the Table takes them uncopied."""
+    for a in cols.values():
+        while isinstance(a, np.ndarray):
+            a.flags.writeable = False
+            a = a.base
+    return Table(cols, eager=eager)
 
 
 def _fill_of(dt) -> object:
