@@ -3,7 +3,7 @@
 
 Generates seeded TPC-H tables at ``--sf`` (default 1: 6,001,215 lineitem,
 10,000 supplier and 800,000 partsupp rows), stored as a deployment stores
-them — int32 keys and dates, f32 measures — and drives five queries
+them — int32 keys and dates, f32 measures — and drives six queries
 through the entry points a user calls (``weldrel.Query`` → ``Evaluate``,
 ``CompiledQuery.run``, ``QueryServer.submit``; the dense group-by through
 ``welddf``), with the Pallas kernels compiled by Mosaic:
@@ -11,6 +11,10 @@ through the entry points a user calls (``weldrel.Query`` → ``Evaluate``,
 * Q6 — filtered revenue sum (``filter_reduce_sum``);
 * Q1 — filtered multi-aggregate group-by on (returnflag, linestatus): its
   two-column key packs into 64 bits, so the TPU gate keeps it generic;
+* Q1 over exact decimals — the same query with the measures as int32
+  hundredths and the flags as dictionary columns: one dense key, int64
+  sums on the exact group route (``dict_group_sum``), equal to the
+  reference bit for bit;
 * revenue by ship date — a dense int32 key of 2,557 days
   (``dict_group_sum``);
 * lineitem ⋈ supplier on suppkey, m:1 (``dict_hash_build`` +
@@ -144,6 +148,42 @@ def ref_q1(t):
             + (int(counts[g]),) for g in np.flatnonzero(counts)}
 
 
+#: the flags' dictionaries, in the generator's code order
+Q1_DICTS = {"l_returnflag": (b"A", b"N", b"R"), "l_linestatus": (b"F", b"O")}
+DECIMALS = ("l_quantity", "l_extendedprice", "l_discount", "l_tax")
+
+
+def decimal_lineitem(t) -> dict:
+    """Q1's columns as a columnar engine stores the spec's types: the
+    DECIMAL(15,2) measures as int32 hundredths (exact from the f32
+    draws), the flags as their codes, the ship date as is."""
+    li = t["lineitem"]
+    out = {c: li[c] for c in ("l_returnflag", "l_linestatus", "l_shipdate")}
+    out.update({c: np.rint(li[c].astype(np.float64) * 100).astype(np.int32)
+                for c in DECIMALS})
+    return out
+
+
+def ref_q1_decimal(li):
+    """Q1's sums in int64 and its averages as sum / count."""
+    m = li["l_shipdate"] <= np.int32(Q1_CUT)
+    qty, price, disc, tax = (li[c][m].astype(np.int64) for c in DECIMALS)
+    disc_price = price * (100 - disc)
+    group = li["l_returnflag"][m] * 2 + li["l_linestatus"][m]
+    out = {}
+    for g in range(6):
+        s = group == g
+        n = int(s.sum())
+        if n:
+            sq, sp, sd = (int(x[s].sum()) for x in (qty, price, disc))
+            out[(Q1_DICTS["l_returnflag"][g // 2],
+                 Q1_DICTS["l_linestatus"][g % 2])] = (
+                sq, sp, int(disc_price[s].sum()),
+                int((disc_price[s] * (100 + tax[s])).sum()),
+                sq / n, sp / n, sd / n, n)
+    return out
+
+
 def ref_ship_revenue(t):
     li = t["lineitem"]
     sums = np.bincount(li["l_shipdate"], weights=li["l_extendedprice"]
@@ -258,6 +298,26 @@ def run(sf: float, seed: int, impl=None, log=print):
                             "+")},
             capacity=8, **kw)
 
+    dec_li = decimal_lineitem(t)
+
+    def q1_decimal(entry, **kw):
+        li = weldrel.Table(dec_li, dictionaries=Q1_DICTS)
+
+        def dec(c):
+            return li.col(c).astype(np.int64)
+
+        qty, price, disc = (dec(c) for c in ("l_quantity",
+                                             "l_extendedprice", "l_discount"))
+        disc_price = price * (100 - disc)
+        q = weldrel.Query(li).filter(li.col("l_shipdate") <= np.int32(Q1_CUT))
+        return entry(q).group_agg(
+            [li.col("l_returnflag"), li.col("l_linestatus")],
+            {"sum_qty": (qty, "+"), "sum_base_price": (price, "+"),
+             "sum_disc_price": (disc_price, "+"),
+             "sum_charge": (disc_price * (100 + dec("l_tax")), "+"),
+             "avg_qty": (qty, "avg"), "avg_price": (price, "avg"),
+             "avg_disc": (disc, "avg")}, **kw)
+
     def join_m1(entry, **kw):
         return entry(weldrel.Query(table("lineitem", join_li))).join(
             table("supplier", ["s_suppkey", "s_nationkey", "s_acctbal"]),
@@ -280,6 +340,8 @@ def run(sf: float, seed: int, impl=None, log=print):
         # name: (query, reference, kernels it must route on the chip)
         "q6": (q6, ref_q6(t), {"filter_reduce_sum"}),
         "q1": (q1, ref_q1(t), set()),
+        "q1_decimal": (q1_decimal, ref_q1_decimal(dec_li),
+                       {"dict_group_sum"}),
         "join_m1": (join_m1, ref_join(
             {c: t["lineitem"][c] for c in join_li}, t["supplier"],
             "l_suppkey", "s_suppkey"),
@@ -293,6 +355,10 @@ def run(sf: float, seed: int, impl=None, log=print):
     def check(name, got, want):
         if name.startswith("join"):
             check_table(got, want, name)
+        elif name == "q1_decimal":
+            if got != want or list(got) != list(want):
+                raise AssertionError(f"q1_decimal: {got} differs from the "
+                                     f"exact reference {want}")
         else:
             check_scalars(got, want, name)
 

@@ -74,6 +74,12 @@ MAP_CHAIN_SLACK_PER_OP = 0.10
 #: MAX_K): keyed accumulation beyond it serves the ref scatter instead.
 SEGMENT_TILE_K = 4096
 
+#: int32 elementwise operations per second of the TPU's vector unit
+#: (8 sublanes x 128 lanes x 4 ALUs at 1.5 GHz: an estimate, no
+#: published peak), which prices VPU-bound kernels such as the exact
+#: group route.
+VPU_INT_OPS = 8 * 128 * 4 * 1.5e9
+
 #: a vectorized binary search (the generic dict-probe lowering) issues
 #: log2(K) dependent random loads per row; each achieves this many
 #: streaming-pass equivalents (gentler than full scatter: the upper tree
@@ -186,20 +192,34 @@ def cost_vecmerger(meta: dict) -> CostEstimate:
 
 
 def cost_dict_group(meta: dict) -> CostEstimate:
-    """Dense-int-key group-by: one-hot segment sums + compaction vs. the
-    generic sort-based dictmerger lowering."""
+    """Dense-int-key group-by: one-hot segment sums (or, for integer
+    values, the exact word kernel) + compaction vs. the generic
+    sort-based dictmerger lowering."""
     n, k = meta.get("n"), meta.get("k")
     if not n or not k:
         return REJECT_UNKNOWN
     e = meta.get("elem_bytes", 8)
     block = meta.get("block", 256)
     np_ = _pad(n, block)
+    j_bytes = n * SORT_BYTES_PER_ROW * max(log2(max(n, 2)), 1.0)
+    jnp_s = _roofline_s(j_bytes, n)
+    words = meta.get("words")
+    if words:
+        # the words actually moved: the input columns read once, the
+        # int32 plane stack (key + one plane per two words) written and
+        # read back; every word of every row is a compare-select and an
+        # add per group on the VPU
+        planes = 1 + (words - 1) // 2
+        k_bytes = n * meta.get("cols", 1) * 4 + 2 * np_ * planes * 4
+        vpu_s = 2.0 * np_ * k * words / VPU_INT_OPS
+        kernel_s = (max(_roofline_s(k_bytes, 0.0), vpu_s)
+                    + 2 * LAUNCH_OVERHEAD_S)
+        return _decide(kernel_s, jnp_s,
+                       f"n={n} K={k} words={words} pad={np_ - n}")
     # kernel: stacked (vals, ones) scratch + one-hot matmul + K-compaction
     k_bytes = np_ * (4 + 2 * e) + 2 * n * e + 4 * k * e
     k_flops = 2.0 * np_ * k * 2 + k * max(log2(max(k, 2)), 1.0)
     kernel_s = _roofline_s(k_bytes, k_flops) + 2 * LAUNCH_OVERHEAD_S
-    j_bytes = n * SORT_BYTES_PER_ROW * max(log2(max(n, 2)), 1.0)
-    jnp_s = _roofline_s(j_bytes, n)
     return _decide(kernel_s, jnp_s, f"n={n} K={k} pad={np_ - n}")
 
 

@@ -56,6 +56,7 @@ from typing import Dict, List, Optional, Tuple
 
 from .. import ir
 from .. import wtypes as wt
+from ...kernels import segment_reduce as _sr
 from ..backend.jaxgen import match_group_probe as _group_probe_shape
 from . import cost as _cost
 from . import registry as reg
@@ -189,6 +190,10 @@ def _tpu_reject(kc: ir.KernelCall) -> Optional[str]:
                 return (f"dtype {','.join(bad)}: the TPU segment kernel "
                         f"accumulates {','.join(seg.tpu_kinds)} only")
     elif kc.kernel == "dict_group_sum":
+        if dict(kc.params).get("exact"):
+            # integer values, 64-bit ones included, enter the kernel as
+            # 32-bit containers split into 16-bit words
+            return None
         kinds = _kinds(kc.ret_ty.val)
     elif kc.kernel in ("map_elementwise", "matmul", "matvec"):
         # the staged body / operands run inside the kernel itself
@@ -356,7 +361,27 @@ def _match_vecmerger(loop: ir.For, dense: Shapes) -> Optional[ir.KernelCall]:
     )
 
 
+def _narrowed(e: ir.Expr) -> ir.Expr:
+    """A value widened by a cast from a narrower int (``i64(x)``) enters
+    the exact group route as that narrower value, sign-extended there:
+    one 32-bit container instead of two."""
+    while isinstance(e, ir.GetField) and isinstance(e.expr, ir.MakeStruct):
+        e = e.expr.items[e.index]  # fusion's {a, b}.$k is b's k-th item
+    if isinstance(e, ir.Cast) and e.ty.is_int:
+        inner = ir.typeof(e.expr)
+        if (isinstance(inner, wt.Scalar) and inner.is_int
+                and wt.elem_bytes(inner) <= wt.elem_bytes(e.ty)):
+            return e.expr
+    return e
+
+
 def _match_dict_group(loop: ir.For, dense: Shapes) -> Optional[ir.KernelCall]:
+    """Dictmerger[+] over a scalar int key that the builder's static
+    capacity bounds, ``[0, capacity)``.  Integer values (a scalar or a
+    struct of them: weldrel's multi-aggregate ``group_agg``) over at most
+    ``segment_reduce.MAX_GROUPS`` keys take the exact word route, whose
+    sums equal int64 arithmetic; a float scalar takes the one-hot MXU
+    segment sums."""
     spec = reg.available("dict_group_sum")
     if spec is None:
         return None
@@ -370,13 +395,17 @@ def _match_dict_group(loop: ir.For, dense: Shapes) -> Optional[ir.KernelCall]:
     kt, vt = nb.ty.key, nb.ty.val
     if not (isinstance(kt, wt.Scalar) and kt.is_int):
         return None
-    if not _scalar_kind_ok(vt, spec):
-        return None
     cap = _static_cap(nb.arg, dense)
     if cap is None:
         return None  # capacity must be statically resolvable
-    if spec.max_segments is not None and cap > spec.max_segments:
-        return None
+    val_tys = vt.fields if isinstance(vt, wt.Struct) else (vt,)
+    exact = cap <= _sr.MAX_GROUPS and all(
+        isinstance(t, wt.Scalar) and t.is_int for t in val_tys)
+    if not exact:
+        if not _scalar_kind_ok(vt, spec):
+            return None
+        if spec.max_segments is not None and cap > spec.max_segments:
+            return None
     b, i, x = loop.func.params
     body = loop.func.body
     cond: Optional[ir.Expr] = None
@@ -391,20 +420,40 @@ def _match_dict_group(loop: ir.For, dense: Shapes) -> Optional[ir.KernelCall]:
         return None
     key_e, val_e = _destructure_pair(body.value)
     per_elem = {i.name, x.name}
-    if not (_elementwise_ok(key_e, {b.name}, per_elem)
-            and _elementwise_ok(val_e, {b.name}, per_elem)):
-        return None
-    if cond is not None and not _elementwise_ok(cond, {b.name}, per_elem):
-        return None
-    fns = [ir.Lambda((i, x), key_e), ir.Lambda((i, x), val_e)]
+    vals = [val_e]
+    if isinstance(vt, wt.Struct):
+        if not (isinstance(val_e, ir.MakeStruct)
+                and len(val_e.items) == len(val_tys)):
+            return None
+        vals = list(val_e.items)
+    if exact:
+        # a literal value (the implicit count's 1) is that many times the
+        # group's count; every other value is computed per row
+        lits = tuple(v.value if isinstance(v, ir.Literal) else None
+                     for v in vals)
+        vals = [_narrowed(v) for v in vals if not isinstance(v, ir.Literal)]
+    for e2 in [key_e] + vals + ([cond] if cond is not None else []):
+        if not _elementwise_ok(e2, {b.name}, per_elem):
+            return None
+    fns = [ir.Lambda((i, x), e2) for e2 in [key_e] + vals]
     if cond is not None:
         fns.append(ir.Lambda((i, x), cond))
+    params: Tuple[Tuple[str, object], ...] = (
+        ("capacity", cap), ("key_np", str(kt.np_dtype.__name__)),
+        ("has_pred", cond is not None))
+    if exact:
+        params += (
+            ("exact", True), ("lits", lits),
+            ("val_nps", tuple(t.np_dtype.__name__ for t in val_tys)),
+            ("words", _sr.words_per_row(
+                ir.typeof(v).np_dtype for v in vals)),
+            # fixed by the kernel's exactness bound, not tuned
+            ("block", _sr.GROUP_BLOCK))
     return ir.KernelCall(
         kernel=spec.name,
         args=tuple(it.data for it in loop.iters),
         ret_ty=wt.DictType(kt, vt),
-        params=(("capacity", cap), ("key_np", str(kt.np_dtype.__name__)),
-                ("has_pred", cond is not None)),
+        params=params,
         fns=tuple(fns),
     )
 
@@ -1045,6 +1094,10 @@ def _call_meta(kc: ir.KernelCall, dense: Shapes,
         )
         meta["k"] = params.get("capacity")
         meta["elem_bytes"] = _elem_bytes(kc.ret_ty)
+        if params.get("exact"):
+            meta["words"] = params["words"]
+            meta["cols"] = len(kc.args)
+            meta["block"] = params["block"]
     elif kc.kernel == "dict_hash_build":
         meta["n"] = next(
             (v for v in (_len_of(a, dense) for a in kc.args) if v), None
@@ -1240,6 +1293,11 @@ def plan_kernels(
                 est = _cost.REJECT_UNKNOWN
         kplan["routed"][kc.kernel] = kplan["routed"].get(kc.kernel, 0) + 1
         stats["kernelize.matched"] += 1
+        if dict(kc.params).get("exact"):
+            # the exact group route's dense key range and words per row
+            for c, v in (("dense_group.keys", meta["k"]),
+                         ("dense_group.words", meta["words"])):
+                kplan[c] = kplan.get(c, 0) + v
         key = f"kernelize.{kc.kernel}"
         stats[key] = stats.get(key, 0) + 1
         n = meta.get("n")

@@ -290,7 +290,9 @@ def _exec_vecmerger_segment_sum(args, params, fns, impl):
 
 
 def _exec_dict_group_sum(args, params, fns, impl):
-    """Dense-int-key group-by-sum: one-hot MXU accumulation + compaction.
+    """Dense-int-key group-by-sum + presence compaction: float values by
+    one-hot MXU accumulation, integer values (``exact`` in params) by the
+    exact word kernel, whose sums and counts equal int64 arithmetic.
 
     The route assumes keys in [0, capacity).  Rows failing the (optional)
     loop predicate are masked out; rows that PASS the predicate but carry
@@ -304,28 +306,32 @@ def _exec_dict_group_sum(args, params, fns, impl):
     elem = _elem_of(arrays)
     cap = int(params["capacity"])
     keys = _as_col(fns[0](idx, elem), n).astype(jnp.int64)
-    vals = _as_col(fns[1](idx, elem), n)
     if params.get("has_pred"):
-        mask = _as_col(fns[2](idx, elem), n).astype(bool)
+        mask = _as_col(fns[-1](idx, elem), n).astype(bool)
     else:
         mask = jnp.ones((n,), dtype=bool)
     inrange = (keys >= 0) & (keys < cap)
     overflow = jnp.any(mask & ~inrange)
     valid = mask & inrange
-    # invalid rows contribute zero to segment 0 (sum identity)
-    seg = jnp.where(valid, keys, 0).astype(jnp.int32)
-    vals_m = jnp.where(valid, vals, jnp.zeros((), vals.dtype))
-    ones = jnp.where(valid, 1, 0).astype(vals.dtype)
-    # one fused launch for sums + presence counts (shared seg-id loads)
-    both = kops.segment_sum_vectors(seg, jnp.stack([vals_m, ones], axis=1),
-                                    num_segments=cap, impl=impl,
-                                    block=params.get("block"))
-    sums, counts = both[:, 0], both[:, 1]
+    if params.get("exact"):
+        counts, outs = _exact_group_sums(fns, params, idx, elem, n, cap,
+                                         jnp.where(valid, keys, -1), impl)
+    else:
+        vals = _as_col(fns[1](idx, elem), n)
+        # invalid rows contribute zero to segment 0 (sum identity)
+        seg = jnp.where(valid, keys, 0).astype(jnp.int32)
+        vals_m = jnp.where(valid, vals, jnp.zeros((), vals.dtype))
+        ones = jnp.where(valid, 1, 0).astype(vals.dtype)
+        # one fused launch for sums + presence counts (shared seg-id loads)
+        both = kops.segment_sum_vectors(
+            seg, jnp.stack([vals_m, ones], axis=1), num_segments=cap,
+            impl=impl, block=params.get("block"))
+        outs, counts = (both[:, 0],), both[:, 1]
     present = counts > 0
     order = jnp.argsort(~present, stable=True)  # front-pack, keys ascending
     key_dtype = np.dtype(params.get("key_np", "int64"))
     keys_out = jnp.arange(cap, dtype=key_dtype)[order]
-    vals_out = sums[order]
+    vals_out = tuple(v[order] for v in outs)
     count = present.sum()
     # Overflow guards, layered: the negative count makes host decode raise
     # (WDict.to_numpy); poisoned keys/values cover traced consumers that
@@ -333,10 +339,29 @@ def _exec_dict_group_sum(args, params, fns, impl):
     # aggregate cannot propagate as a plausible number.
     count = jnp.where(overflow, -count - 1, count)
     keys_out = jnp.where(overflow, jnp.full_like(keys_out, -1), keys_out)
-    if jnp.issubdtype(vals_out.dtype, jnp.floating):
-        vals_out = jnp.where(overflow, jnp.full_like(vals_out, jnp.nan),
-                             vals_out)
-    return WDict(keys_out, vals_out, count)
+    vals_out = tuple(
+        jnp.where(overflow, jnp.full_like(v, jnp.nan), v)
+        if jnp.issubdtype(v.dtype, jnp.floating) else v for v in vals_out)
+    return WDict(keys_out, vals_out if len(vals_out) > 1 else vals_out[0],
+                 count)
+
+
+def _exact_group_sums(fns, params, idx, elem, n, cap, seg, impl):
+    """The exact route's counts and one column per value field, in the
+    field's dtype: a literal field is that many times the count, every
+    other the word kernel's int64 sum, narrowed as the field wraps."""
+    lits, nps = params["lits"], params["val_nps"]
+    computed = [_as_col(fns[1 + k](idx, elem), n)
+                for k in range(sum(v is None for v in lits))]
+    counts, sums = kops.group_sum_exact(seg.astype(jnp.int32), computed,
+                                        cap, impl=impl,
+                                        block=params.get("block"))
+    sums = iter(sums)
+    outs = tuple(
+        (next(sums) if lit is None else counts if lit == 1
+         else counts * lit).astype(np_dt)
+        for lit, np_dt in zip(lits, nps))
+    return counts, outs
 
 
 def _exec_dict_hash_build(args, params, fns, impl):
@@ -674,6 +699,13 @@ def _fp_dict_group(arg_shapes, itemsize, params):
     n = arg_shapes[0][0] if arg_shapes and arg_shapes[0] else 0
     cap = int(params.get("capacity", 0))
     pad = _pad_of(n, params.get("block") or _sr.BLOCK_N)
+    if params.get("exact"):
+        # staged keys/mask/values, the padded int32 plane stack (the key
+        # and two 16-bit words per container) and the chunk partials
+        planes = 1 + (params["words"] - 1) // 2
+        return ((n + pad) * (4 * planes + 9 + 8 * len(params["lits"]))
+                + cap * params["words"] * 128 * 4
+                * (-(-(n + pad) // _sr.CHUNK_ROWS)))
     # staged keys/mask + the stacked (n, 2) value matrix + K-compaction
     return (n + pad) * (4 + 2 * itemsize + 1) + cap * (3 * itemsize + 8)
 
@@ -922,7 +954,9 @@ register(KernelSpec(
     builder="dictmerger[+]",
     elem_kinds=("f32", "f64", "i32", "i64"),
     description="group-by-sum with dense int keys in [0, capacity) via "
-                "segment_sum + presence compaction",
+                "segment_sum + presence compaction; integer values (a "
+                "scalar or weldrel's multi-aggregate struct) over at most "
+                "64 keys take the exact word kernel (group_sum_exact)",
     max_segments=_sr.MAX_K,
     tpu_kinds=("f32",),
     execute=_exec_dict_group_sum,

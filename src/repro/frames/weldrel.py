@@ -29,9 +29,16 @@ class Table:
     taken as it is, so writing into the caller's array afterwards
     changes no answer.  A lazy table's column is uploaded to the device
     by the first program that binds it; every later program binds that
-    device copy, which is freed with the table."""
+    device copy, which is freed with the table.
 
-    def __init__(self, columns: Dict[str, np.ndarray], eager: bool = False):
+    ``dictionaries`` declares dictionary-coded columns, ``{name:
+    values}``: the column holds integer codes into ``values`` (a
+    ``CHAR(1)`` flag stored as codes of ``[b"A", b"N", b"R"]``), checked
+    here, once, to lie in ``[0, len(values))``.  ``group_agg`` keys on
+    such columns through one dense group id and answers in ``values``."""
+
+    def __init__(self, columns: Dict[str, np.ndarray], eager: bool = False,
+                 dictionaries: Optional[Dict[str, Sequence]] = None):
         self.eager = eager
         self.cols = {
             k: weldnp.array(_snapshot(v), eager=eager)
@@ -40,9 +47,20 @@ class Table:
         if not eager:
             for c in self.cols.values():
                 c.obj.resident = DeviceSlot()
+        self.dictionaries = {
+            name: _checked_dictionary(name, _host(self.cols[name]), values)
+            for name, values in (dictionaries or {}).items()
+        }
 
     def col(self, name: str) -> weldnp.ndarray:
         return self.cols[name]
+
+    def dictionary_of(self, col: weldnp.ndarray) -> Optional[tuple]:
+        """The declared dictionary of one of this table's columns."""
+        for name, c in self.cols.items():
+            if c is col:
+                return self.dictionaries.get(name)
+        return None
 
 
 class Query:
@@ -211,19 +229,43 @@ class Query:
     ):
         """GROUP BY keys; all aggregates share ONE dictmerger pass.
         Returns {key_tuple: (agg,...)} (+ implicit count as last value).
+        An aggregate is ``"+"`` (a sum) or ``"avg"`` (that sum over the
+        group's count, ``sum / count``, computed on the host from the
+        sum).
 
-        NOTE: grouped multi-aggregates build a struct-valued dictmerger,
-        which the kernel planner does not yet route (ROADMAP: multi-agg
-        fusion) — ``kernelize=True`` is accepted for API symmetry but
-        currently always falls back to the generic sort-based path."""
+        Keys that are all dictionary columns of the table (``Table(...,
+        dictionaries=...)``) group through one dense int32 id, the codes
+        times row-major strides, in ``[0, product of the dictionary
+        sizes)``: the builder's capacity is that product, and the answer
+        is keyed by dictionary values and ordered by code.  Over a range
+        within the kernel's tile and with integer values, the planner
+        routes the pass onto the exact group route, whose sums equal
+        int64 arithmetic.  Other keys pack into a struct key, as
+        ``capacity`` bounds."""
+        names = list(vals)
+        ops = {vals[n][1] for n in names} | {"+"}
+        assert ops <= {"+", "avg"}, "grouped aggregates support sum/count/avg"
+        dicts = [self.table.dictionary_of(k) for k in keys]
+        # a dense id must fit int32
+        dense = (bool(dicts) and all(d is not None for d in dicts)
+                 and np.prod([len(d) for d in dicts]) < 2 ** 31)
+        # each distinct value column is summed once (an avg reuses it)
+        summed: List[weldnp.ndarray] = []
+        slot_of = {}
+        for n in names:
+            col = vals[n][0]
+            if id(col) not in slot_of:
+                slot_of[id(col)] = len(summed)
+                summed.append(col)
+        fields = [(slot_of[id(vals[n][0])], vals[n][1]) for n in names]
+        finish = _group_finish(fields, dicts if dense else None)
+
         if self.table.eager:
-            # same contract as the lazy path below: anything but "+"
-            # must fail loudly instead of silently summing
-            ops = {vals[n][1] for n in vals} | {"+"}
-            assert ops == {"+"}, "grouped aggregates support sum/count"
             m = self.pred._eager if self.pred is not None else slice(None)
             karrs = [k._eager[m] for k in keys]
-            varrs = [vals[n][0]._eager[m] for n in vals]
+            varrs = [v._eager[m] for v in summed]
+            if dense:
+                return finish(_eager_dense_groups(karrs, varrs, dicts))
             # per-dtype merger identities: an int value column accumulates
             # as ints and decodes as ints, exactly like the lazy dict path
             # (the old [0.0]*n seed floated every aggregate)
@@ -242,13 +284,12 @@ class Query:
                 for j, v in enumerate(varrs):
                     slotv[j] += v[row_idx]
                 slotv[-1] += 1
-            return {
+            return finish({
                 k: tuple(x.item() if isinstance(x, np.generic) else x
                          for x in v)
                 for k, v in out.items()
-            }
+            })
 
-        names = list(vals)
         deps: List[WeldObject] = []
         ids: List[ir.Expr] = []
         seen: Dict[str, int] = {}
@@ -261,32 +302,35 @@ class Query:
             return seen[arr.obj.obj_id]
 
         key_slots = [slot(k) for k in keys]
-        val_slots = [slot(vals[n][0]) for n in names]
+        val_slots = [slot(v) for v in summed]
         pred_slot = slot(self.pred) if self.pred is not None else None
-        ops = {vals[n][1] for n in names} | {"+"}
-        assert ops == {"+"}, "grouped aggregates support sum/count"
 
-        key_ty = wt.Struct(tuple(_ety(s, ids) for s in key_slots)) \
-            if len(key_slots) > 1 else _ety(key_slots[0], ids)
-        val_ty = wt.Struct(
-            tuple(_ety(s, ids) for s in val_slots) + (wt.I64,)
-        )
-        bt = wt.DictMerger(key_ty, val_ty, "+")
         elem_ty = (
             wt.Struct(tuple(_ety(i, ids) for i in range(len(ids))))
             if len(ids) > 1 else _ety(0, ids)
         )
-        b = ir.Ident(ir.fresh("b"), bt)
-        i = ir.Ident(ir.fresh("i"), wt.I64)
         x = ir.Ident(ir.fresh("x"), elem_ty)
 
         def field(k: int) -> ir.Expr:
             return ir.GetField(x, k) if len(ids) > 1 else x
 
-        key_expr = (
-            ir.MakeStruct(tuple(field(s) for s in key_slots))
-            if len(key_slots) > 1 else field(key_slots[0])
+        if dense:
+            key_ty, key_expr, capacity = _dense_key(
+                [field(s) for s in key_slots],
+                [_ety(s, ids) for s in key_slots], dicts)
+        else:
+            key_ty = wt.Struct(tuple(_ety(s, ids) for s in key_slots)) \
+                if len(key_slots) > 1 else _ety(key_slots[0], ids)
+            key_expr = (
+                ir.MakeStruct(tuple(field(s) for s in key_slots))
+                if len(key_slots) > 1 else field(key_slots[0])
+            )
+        val_ty = wt.Struct(
+            tuple(_ety(s, ids) for s in val_slots) + (wt.I64,)
         )
+        bt = wt.DictMerger(key_ty, val_ty, "+")
+        b = ir.Ident(ir.fresh("b"), bt)
+        i = ir.Ident(ir.fresh("i"), wt.I64)
         val_expr = ir.MakeStruct(
             tuple(field(s) for s in val_slots) + (ir.Literal(1, wt.I64),)
         )
@@ -301,7 +345,7 @@ class Query:
         )
         obj = NewWeldObject(deps, ir.Result(loop))
         return self._finish(
-            obj, lambda v: v,
+            obj, finish,
             op="group_agg", tables={"table": self.table},
             kernelize=kernelize, kernel_impl=kernel_impl,
             collect_stats=collect_stats)
@@ -1260,6 +1304,100 @@ class CompiledQuery:
                 inputs[iname] = (_host(col), None if col.is_eager
                                  else col.obj.resident)
         return self.staged.finalize(self.handle.run(inputs))
+
+
+def _checked_dictionary(name: str, codes: np.ndarray, values) -> tuple:
+    """``values`` as a tuple, once every code of column ``name`` is an
+    integer in ``[0, len(values))``."""
+    values = tuple(values)
+    if not np.issubdtype(codes.dtype, np.integer):
+        raise TypeError(f"dictionary column {name!r} holds {codes.dtype}, "
+                        "not integer codes")
+    if not values:
+        raise ValueError(f"dictionary of column {name!r} is empty")
+    if codes.size and (int(codes.min()) < 0
+                       or int(codes.max()) >= len(values)):
+        raise ValueError(
+            f"dictionary column {name!r} holds codes in "
+            f"[{int(codes.min())}, {int(codes.max())}], outside its "
+            f"{len(values)}-value dictionary")
+    return values
+
+
+def _strides(dicts: List[tuple]) -> List[int]:
+    """Row-major strides of the dense group id over the dictionaries."""
+    out, step = [], 1
+    for d in reversed(dicts):
+        out.append(step)
+        step *= len(d)
+    return out[::-1]
+
+
+def _dense_key(codes: List[ir.Expr], tys: List[wt.Scalar],
+               dicts: List[tuple]):
+    """``(key type, key expression, capacity)`` of the dense group id:
+    the sum of each int32 code times its stride."""
+    key: Optional[ir.Expr] = None
+    for e, ty, st in zip(codes, tys, _strides(dicts)):
+        e = e if ty == wt.I32 else ir.Cast(e, wt.I32)
+        if st != 1:
+            e = ir.BinOp("*", e, ir.Literal(st, wt.I32))
+        key = e if key is None else ir.BinOp("+", key, e)
+    return wt.I32, key, int(np.prod([len(d) for d in dicts]))
+
+
+def _eager_dense_groups(karrs, varrs, dicts) -> dict:
+    """The eager path's ``{dense id: (sums..., count)}``: each sum in its
+    column's dtype, as the lazy dictmerger merges it."""
+    dk = np.zeros(karrs[0].shape[0], np.int64)
+    for c, st in zip(karrs, _strides(dicts)):
+        dk += c.astype(np.int64) * st
+    size = int(np.prod([len(d) for d in dicts]))
+    counts = np.bincount(dk, minlength=size)
+    sums = []
+    for v in varrs:
+        acc = np.zeros(size, v.dtype)
+        np.add.at(acc, dk, v)
+        sums.append(acc)
+    return {int(k): tuple(a[k].item() for a in sums) + (int(counts[k]),)
+            for k in np.flatnonzero(counts)}
+
+
+def _group_finish(fields: List[Tuple[int, str]],
+                  dicts: Optional[List[tuple]]) -> Callable:
+    """The host finish of a grouped aggregate: from ``{key: (sums...,
+    count)}`` to ``{key: (agg..., count)}``, an ``"avg"`` being its sum
+    over the count.  With ``dicts`` (dense dictionary keys) the key is the
+    dense id: it decodes to the dictionary values and the groups are
+    ordered by it, inside weldtrace's ``group.finish`` span (counters
+    ``groups`` and ``words``, the 64-bit values decoded)."""
+    if dicts is None and all(op == "+" for _, op in fields) \
+            and [s for s, _ in fields] == list(range(len(fields))):
+        return lambda v: v  # the dictmerger's own answer
+
+    def row(v):
+        cnt = v[-1]
+        return tuple(v[s] if op == "+" else v[s] / cnt
+                     for s, op in fields) + (cnt,)
+
+    if dicts is None:
+        return lambda v: {k: row(x) for k, x in v.items()}
+    strides = _strides(dicts)
+
+    def decode(k):
+        vals = tuple(d[(k // st) % len(d)] for d, st in zip(dicts, strides))
+        return vals[0] if len(vals) == 1 else vals
+
+    def finish(v):
+        from ..core import obs
+
+        with obs.span("group.finish") as sp:
+            out = {decode(k): row(v[k]) for k in sorted(v)}
+            sp.count("groups", len(out))
+            sp.count("words", sum(len(x) for x in v.values()))
+        return out
+
+    return finish
 
 
 def _host(col: weldnp.ndarray) -> np.ndarray:

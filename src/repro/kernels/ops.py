@@ -164,6 +164,32 @@ def segment_sum_vectors(seg_ids, vals, num_segments: int,
                 block=block or _sr.BLOCK_N)
 
 
+# -- exact group sums (integer dictmerger route) ----------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("n_groups", "impl", "block"))
+def _gse(keys, values, n_groups, impl, block):
+    if impl == "ref":
+        return _ref.group_sum_exact(keys, values, n_groups)
+    per = _sr.containers(v.dtype for v in values)
+    tops = tuple(t for c in per for t in c)
+    planes = _sr.group_planes(keys, values, block)
+    with _kernel_mode(planes):
+        part = _sr.group_sum_words(planes, n_groups, tops, block=block,
+                                   interpret=(impl == "interpret"))
+    return _sr.combine_partials(part, n_groups, [len(c) for c in per])
+
+
+def group_sum_exact(keys, values, n_groups: int,
+                    impl: Optional[Impl] = None, block: Optional[int] = None):
+    """Exact per-group counts and sums of integer columns: ``keys`` int32
+    group ids in ``[0, n_groups)`` (any other id leaves its row out),
+    ``values`` a sequence of integer columns.  Returns ``(counts,
+    sums)``, int64 ``(n_groups,)`` arrays, ``sums`` one per value."""
+    return _gse(keys, tuple(values), n_groups=n_groups, impl=_resolve(impl),
+                block=block or _sr.GROUP_BLOCK)
+
+
 # -- dict build / probe (hash-join route) -----------------------------------------
 
 

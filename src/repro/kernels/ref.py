@@ -135,6 +135,19 @@ def group_probe(table_keys, offsets, count, queries):
             jnp.where(found, sizes, jnp.int32(0)))
 
 
+def group_sum_exact(keys, values, n_groups):
+    """int64 counts and sums per group id in [0, n_groups); rows with any
+    other id are left out."""
+    seg = jnp.where((keys >= 0) & (keys < n_groups), keys, n_groups)
+
+    def total(v):
+        return jax.ops.segment_sum(v.astype(jnp.int64), seg,
+                                   num_segments=n_groups + 1)[:n_groups]
+
+    return total(jnp.ones(keys.shape, jnp.int64)), tuple(
+        total(v) for v in values)
+
+
 def segment_sum_vectors(seg_ids, vals, num_segments):
     return jax.ops.segment_sum(vals, seg_ids, num_segments=num_segments)
 

@@ -74,6 +74,12 @@ CASES = {
         lambda s, v: ops.segment_sum_vectors(s, v, SHIP_DAYS,
                                              impl="pallas"),
         [_i32(LINEITEM), ((LINEITEM, 2), jnp.float32)]),
+    # Q1's exact group route: 6 dense keys, three narrow values and two
+    # int64 ones (the discounted price and the charge)
+    "group_sum_exact": (
+        lambda k, a, b, c, d, e: ops.group_sum_exact(
+            k, [a, b, c, d, e], 6, impl="pallas"),
+        [_i32(LINEITEM)] * 4 + [((LINEITEM,), jnp.int64)] * 2),
     "map_elementwise": (
         lambda a, b: ops.map_elementwise(lambda x, y: x * (1.0 - y) + x,
                                          [a, b], impl="pallas"),
