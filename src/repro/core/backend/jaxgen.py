@@ -349,11 +349,21 @@ def _masked_reduce(bt: wt.Merger, value, mask):
     return jax.tree_util.tree_map(red, value, ident)
 
 
+def front_pack(keep, cols):
+    """Stable partition by ``keep``: kept rows first, each side in row
+    order — ``argsort(~keep, stable=True)`` applied to every column, as
+    ONE sort keyed on ``~keep`` that carries the columns as payloads, so
+    the permutation moves them in one pass instead of a gather per
+    column.  ``cols`` is a column or a (nested) tuple of them (struct
+    fields), each as long as ``keep``; the result has its structure."""
+    leaves, tree = jax.tree_util.tree_flatten(cols)
+    packed = jax.lax.sort((~keep, *leaves), num_keys=1, is_stable=True)
+    return jax.tree_util.tree_unflatten(tree, packed[1:])
+
+
 def _compact(vals, mask) -> WVec:
     """Front-pack valid elements (stable) — TPU compaction via sort."""
-    order = jnp.argsort(~mask, stable=True)
-    packed = _gather_struct(vals, order)
-    return WVec(packed, count=mask.sum())
+    return WVec(front_pack(mask, vals), count=mask.sum())
 
 
 def _pack_keys(keys):

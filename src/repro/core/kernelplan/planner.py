@@ -1180,6 +1180,18 @@ def _call_meta(kc: ir.KernelCall, dense: Shapes,
     return meta
 
 
+def _frontpack_cols(params: dict) -> int:
+    """The output columns a ``hash_probe`` call's front-pack sort
+    carries: one for a single-column probe, every column of a fused
+    probe but a left join's without a predicate (no rows dropped, no
+    sort)."""
+    if "cols" not in params:
+        return 1
+    if params["how"] == "left" and not params.get("has_pred"):
+        return 0
+    return len(params["cols"])
+
+
 # ---------------------------------------------------------------------------
 # the pass
 # ---------------------------------------------------------------------------
@@ -1298,6 +1310,10 @@ def plan_kernels(
             for c, v in (("dense_group.keys", meta["k"]),
                          ("dense_group.words", meta["words"])):
                 kplan[c] = kplan.get(c, 0) + v
+        if kc.kernel == "hash_probe":
+            # the output columns the probe's one front-pack sort carries
+            kplan["frontpack.cols"] = (kplan.get("frontpack.cols", 0)
+                                       + _frontpack_cols(dict(kc.params)))
         key = f"kernelize.{kc.kernel}"
         stats[key] = stats.get(key, 0) + 1
         n = meta.get("n")

@@ -43,7 +43,7 @@ from ...kernels import map_chain as _mc
 from ...kernels import ops as kops
 from ...kernels import segment_reduce as _sr
 from ...kernels import tiled_matmul as _tm
-from ..backend.jaxgen import _pack_keys, group_expand
+from ..backend.jaxgen import _pack_keys, front_pack, group_expand
 from ..backend.values import WDict, WGroup, WVec
 from . import cost as _cost
 
@@ -328,10 +328,9 @@ def _exec_dict_group_sum(args, params, fns, impl):
             impl=impl, block=params.get("block"))
         outs, counts = (both[:, 0],), both[:, 1]
     present = counts > 0
-    order = jnp.argsort(~present, stable=True)  # front-pack, keys ascending
     key_dtype = np.dtype(params.get("key_np", "int64"))
-    keys_out = jnp.arange(cap, dtype=key_dtype)[order]
-    vals_out = tuple(v[order] for v in outs)
+    keys_out, vals_out = front_pack(  # keys ascending
+        present, (jnp.arange(cap, dtype=key_dtype), tuple(outs)))
     count = present.sum()
     # Overflow guards, layered: the negative count makes host decode raise
     # (WDict.to_numpy); poisoned keys/values cover traced consumers that
@@ -510,18 +509,17 @@ def _exec_hash_probe(args, params, fns, impl):
             out = vcol[jnp.clip(pos, 0, vcol.shape[0] - 1)]
     else:
         out = _as_col(fns[1](idx, elem), n)
-    order = jnp.argsort(~found, stable=True)  # front-pack kept rows
     count = jnp.where(jnp.asarray(d.count, jnp.int64) < 0,
                       jnp.int64(-1), found.sum().astype(jnp.int64))
-    return WVec(out[order], count=count)
+    return WVec(front_pack(found, out), count=count)
 
 
 def _exec_hash_probe_fused(args, params, fns, impl):
     """Horizontally fused join probe: ONE ``dict_probe`` launch computes
     the found-mask/positions for the (possibly multi-column, packed)
     keys, then EVERY output column reuses them — build-side columns as
-    plain gathers, probe-side columns as staged expressions, and all
-    columns sharing a single front-pack sort.
+    plain gathers, probe-side columns as staged expressions, and ONE
+    front-pack sort carrying every column (:func:`front_pack`).
 
     ``how`` selects the row semantics: ``inner`` keeps found rows,
     ``anti`` keeps misses (left columns only), and ``left`` keeps every
@@ -556,10 +554,10 @@ def _exec_hash_probe_fused(args, params, fns, impl):
     if keep is None:  # left join, no predicate: every row survives
         count = jnp.where(poisoned, jnp.int64(-1), jnp.int64(n))
         return tuple(WVec(c, count=count) for c in outs)
-    order = jnp.argsort(~keep, stable=True)  # ONE shared front-pack
     count = jnp.where(poisoned, jnp.int64(-1),
                       keep.sum().astype(jnp.int64))
-    return tuple(WVec(c[order], count=count) for c in outs)
+    # ONE shared front-pack: a sort that carries every column
+    return tuple(WVec(c, count=count) for c in front_pack(keep, tuple(outs)))
 
 
 def _exec_group_build(args, params, fns, impl):

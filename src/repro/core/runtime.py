@@ -422,7 +422,8 @@ def _optimize_stage(low: LoweredProgram) -> OptimizedProgram:
             expr = plan_kernels(expr, input_shapes=low.shapes, stats=stats,
                                 mode=low.mode, impl=low.kernel_impl)
             sp.set("matched", stats.get("kernelize.matched", 0))
-            for c in ("dense_group.keys", "dense_group.words"):
+            for c in ("dense_group.keys", "dense_group.words",
+                      "frontpack.cols"):
                 if c in stats["kernelplan"]:
                     sp.count(c, stats["kernelplan"][c])
         if stats.get("kernelize.matched"):

@@ -21,7 +21,11 @@ ONE launch for every output column — inner joins front-pack by the
 found mask, left joins keep every row and select per-dtype fills where
 ``found`` is false, anti joins front-pack by its negation — all from
 the same ``(pos, found)`` pair (``kernelplan.registry``,
-``_exec_hash_probe_fused``).
+``_exec_hash_probe_fused``).  The front-pack is one stable sort keyed
+on the mask that carries every output column as a payload
+(``jaxgen.front_pack``), so no output column is gathered through a
+permutation; the build columns' gathers ``vals[pos]`` are the only
+gathers left.
 
 Contract (shared with ``ref.dict_probe``): queries and table keys share
 one key space (int32 for a single key column of at most 32 bits, else

@@ -3,6 +3,8 @@ oracle on the eager, lazy-generic, and kernelized paths; kernel-level
 ref/interpret parity for the open-addressing build and the one-hot
 probe; planner routing decisions (probed dicts take the hash route, the
 dense group-by route is untouched, the cost gate rejects tiny inputs)."""
+import re
+
 import numpy as np
 import pytest
 
@@ -88,6 +90,46 @@ def test_join_kernelized_routes_and_matches():
     assert st["kernelize.hash_probe"] == 1
     assert st["kernelplan"]["routed"]["hash_probe"] == 1
     _check(out, np_join(lcols, rcols, "key"))
+
+
+def _hlo_ops(txt, op):
+    """``(result shape, operand shapes)`` of every ``op`` instruction of
+    an HLO module's text."""
+    shape, found = {}, []
+    line_re = re.compile(r"\s*(?:ROOT )?([\w.\-]+) = (\([^)]*\)|\S+) "
+                         r"(\w[\w\-]*)\(([^)]*)\)")
+    for line in txt.splitlines():
+        m = line_re.match(line)
+        if m:
+            shape[m.group(1)] = m.group(2)
+            if m.group(3) == op:
+                found.append((m.group(2), m.group(4)))
+    return [(res, [shape.get(o.strip()) for o in ops.split(",")])
+            for res, ops in found]
+
+
+def test_join_probe_front_packs_with_one_carried_sort():
+    """The m:1 inner join's 4 output columns (2 probe, 2 build) leave the
+    probe through ONE sort over the probe's rows that carries them, not
+    an argsort and a gather of each column through its order: no gather
+    reads an operand of the probe's length, and the only gathers to it
+    are the 2 build columns'."""
+    lcols, rcols = _data()
+    n, k = lcols["key"].size, rcols["key"].size
+    cq = weldrel.Query(weldrel.Table(lcols)).stage().join(
+        weldrel.Table(rcols), on="key", kernelize="always",
+        kernel_impl="interpret").compile()
+    h = cq.handle
+    txt = h._jitted.lower(*h._low.arrays).compiler_ir("hlo").as_hlo_text()
+    probe = [ops for _, ops in _hlo_ops(txt, "sort")
+             if ops[0].startswith(f"pred[{n}]")]
+    assert len(probe) == 1 and len(probe[0]) == 1 + 4
+    assert all(o.split("{")[0].endswith(f"[{n}]") for o in probe[0])
+    gathers = _hlo_ops(txt, "gather")
+    assert not [ops for _, ops in gathers if f"[{n}]" in ops[0]]
+    into = sorted(ops[0].split("{")[0] for res, ops in gathers
+                  if res.split("{")[0].endswith(f"[{n}]"))
+    assert into == [f"f64[{k}]", f"s64[{k}]"]
 
 
 def test_join_with_filter_predicate():

@@ -694,3 +694,60 @@ def test_cached_stats_returned_as_copy_mutation_cannot_poison():
     assert st2["kernelize.matched"] == 1
     assert st2["bounds.admitted"] is True
     assert "fake builder line" not in st2["bounds.builders"]
+
+
+# ---------------------------------------------------------------------------
+# front_pack: one carried sort == argsort(~keep, stable) + a gather per column
+# ---------------------------------------------------------------------------
+
+
+def _keep(kind, n, r):
+    return {"all": np.ones(n, bool), "none": np.zeros(n, bool),
+            "rand70": r.random(n) < 0.7,
+            "alternating": np.arange(n) % 2 == 0}[kind]
+
+
+def _column(kind, n, r):
+    if kind == "int32":
+        return r.integers(-2 ** 31, 2 ** 31, n, dtype=np.int32)
+    if kind == "float32":  # NaN and -0.0 must move as bit patterns
+        v = r.standard_normal(n).astype(np.float32)
+        v[::7] = np.nan
+        v[3::7] = -0.0
+        return v
+    if kind == "int64":
+        return r.integers(2 ** 32, 2 ** 62, n, dtype=np.int64) * \
+            np.where(r.random(n) < 0.5, -1, 1)
+    if kind == "bool":
+        return r.random(n) < 0.5
+    return (_column("int64", n, r), (_column("float32", n, r),
+                                     _column("bool", n, r)))
+
+
+def _bits(v):
+    a = np.asarray(v)
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+@pytest.mark.parametrize("n", [0, 1, 4097])
+@pytest.mark.parametrize("col", ["int32", "float32", "int64", "bool",
+                                 "struct"])
+@pytest.mark.parametrize("keep", ["all", "none", "rand70", "alternating"])
+def test_front_pack_is_argsort_and_gather_bit_for_bit(keep, col, n):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.backend.jaxgen import front_pack
+
+    r = np.random.default_rng(16)
+    k = jnp.asarray(_keep(keep, n, r))
+    cols = jax.tree_util.tree_map(jnp.asarray, _column(col, n, r))
+    got = front_pack(k, cols)
+    order = jnp.argsort(~k, stable=True)
+    want = jax.tree_util.tree_map(lambda c: c[order], cols)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(_bits(g), _bits(w))

@@ -2,7 +2,8 @@
 dictionary keys is a ``group.finish`` span of the request, inside
 ``frames.finalize``, counting its groups and decoded words; the
 set-up's ``kernelplan`` span counts the dense key range and the words
-the kernel sums per row."""
+the kernel sums per row, and, for a join, the output columns its
+probe's one front-pack sort carries."""
 import numpy as np
 import pytest
 
@@ -65,3 +66,26 @@ def test_undeclared_keys_open_no_group_finish():
     with QueryServer(workers=1) as srv:
         srv.submit(_staged(t)).result()
     assert not [s for s in obs.spans() if s.name == "group.finish"]
+
+
+@pytest.mark.parametrize("how,pred,cols", [("inner", False, 4),
+                                           ("left", False, 0),
+                                           ("left", True, 4),
+                                           ("anti", False, 2)])
+def test_kernelplan_span_counts_the_front_packed_columns(how, pred, cols):
+    from repro.core import runtime
+
+    rng = np.random.default_rng(16)
+    n, k = 777, 37
+    t = weldrel.Table({"key": rng.integers(0, 50, n), "lv": rng.random(n)})
+    r = weldrel.Table({"key": np.arange(k), "rv": rng.random(k),
+                       "rw": rng.integers(0, 9, k)})
+    q = weldrel.Query(t)
+    if pred:
+        q = q.filter(t.col("lv") > 0.5)
+    runtime.clear_cache()
+    obs.enable()
+    q.join(r, on="key", how=how, kernelize="always", kernel_impl="ref")
+    plan = [s for s in obs.spans() if s.name == "kernelplan"]
+    assert len(plan) == 1
+    assert plan[0].counters == {"frontpack.cols": cols}
